@@ -26,7 +26,7 @@ _CHILD = textwrap.dedent("""
     from repro.core import DistributedEngine
     from repro.core.fusion import FedAvg
 
-    from repro.utils.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     n, p = 64, 23_000
     rng = np.random.default_rng(0)
@@ -56,8 +56,7 @@ _CHILD = textwrap.dedent("""
         uu = jax.lax.all_gather(uu, "model", axis=1, tiled=True)
         wl = jax.lax.all_gather(w_, ("pod", "data"), tiled=True)
         return f.fuse(uu, wl)
-    from repro.utils.compat import shard_map
-    gfn = jax.jit(shard_map(gather_all, mesh=mesh,
+    gfn = jax.jit(jax.shard_map(gather_all, mesh=mesh,
         in_specs=(P(("pod","data"), "model"), P(("pod","data"))),
         out_specs=P(), check_vma=False))
     out["gather_all"] = bench(lambda: gfn(us, ws))

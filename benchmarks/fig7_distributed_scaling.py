@@ -24,7 +24,7 @@ _CHILD = textwrap.dedent("""
     from repro.core import DistributedEngine
     from repro.core.fusion import FedAvg, IterAvg
     d = int(sys.argv[1]); n = int(sys.argv[2]); p = int(sys.argv[3])
-    from repro.utils.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((d, 1), ("data", "model"))
     rng = np.random.default_rng(0)
     u = rng.normal(size=(n, p)).astype(np.float32)

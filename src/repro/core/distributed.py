@@ -47,12 +47,17 @@ from repro.core.compress import BLOCK, CompressedBlock
 from repro.core.fusion.base import FusionAlgorithm
 from repro.core.fusion.robust import GeometricMedian, Krum, Zeno
 from repro.core.local import StreamReport, _check_scale
-from repro.utils.compat import shard_map
 from repro.utils.jitcache import CompiledCache, bucket_rows, fusion_cache_key
 
 
 def _device_put(mesh: Mesh, x, spec: P):
-    return jax.device_put(jnp.asarray(x), NamedSharding(mesh, spec))
+    """Place ``x`` on the mesh. A host array goes straight to its shards
+    (staging it whole on the default device first would put every
+    (n, P) block on chip 0 before the reshard); a device array is
+    resharded where it lives."""
+    if not isinstance(x, jax.Array):
+        x = np.asarray(x)
+    return jax.device_put(x, NamedSharding(mesh, spec))
 
 
 @dataclasses.dataclass
@@ -127,10 +132,13 @@ class DistributedEngine:
         pad_n = self._padded_rows(n, fusion.reducible) - n
         pad_p = (-P_) % (self._n_param_shards * self._n_client_shards)
         if pad_n or pad_p:
-            updates = jnp.pad(jnp.asarray(updates), ((0, pad_n), (0, pad_p)))
+            # pad where the matrix lives: host blocks stay on the host
+            # until _device_put shards them
+            xp = jnp if isinstance(updates, jax.Array) else np
+            updates = xp.pad(updates, ((0, pad_n), (0, pad_p)))
             # zero weight => padded rows contribute nothing to reducible
             # fusions; robust paths mask them explicitly
-            weights = jnp.pad(jnp.asarray(weights), (0, pad_n))
+            weights = jnp.pad(weights, (0, pad_n))
         out = self._dispatch(fusion, updates, weights, n)
         return out[:P_]
 
@@ -189,13 +197,13 @@ class DistributedEngine:
             def mapper(u, w):
                 return self._partials(fusion, u, w)
 
-            return shard_map(
+            return jax.shard_map(
                 mapper, mesh=mesh, in_specs=(in_u, in_w),
                 out_specs=(out, P()), check_vma=False,
             )
 
         u = _device_put(mesh, updates, in_u)
-        w = _device_put(mesh, jnp.asarray(weights, jnp.float32), in_w)
+        w = _device_put(mesh, weights, in_w)
         fn = self._key_get(fusion, updates, None, build, u, w)
         wsum, tot = fn(u, w)
         # combine stays OUTSIDE the compiled closure: FedAvgM/FedAdam keep
@@ -220,7 +228,7 @@ class DistributedEngine:
                 u = u[:n_real]
                 return fusion.fuse(u, None)
 
-            return shard_map(
+            return jax.shard_map(
                 mapper, mesh=mesh, in_specs=(in_u,), out_specs=out,
                 check_vma=False,
             )
@@ -244,7 +252,7 @@ class DistributedEngine:
                 idx = fusion.select_from_gram(gram)
                 return jnp.mean(uf[:n_real][idx], axis=0)
 
-            return shard_map(
+            return jax.shard_map(
                 mapper, mesh=mesh, in_specs=(in_u,), out_specs=out,
                 check_vma=False,
             )
@@ -271,7 +279,7 @@ class DistributedEngine:
                 _, idx = jax.lax.top_k(s, keep)
                 return jnp.mean(uf[:n_real][idx], axis=0)
 
-            return shard_map(
+            return jax.shard_map(
                 mapper, mesh=mesh, in_specs=(in_u, P(all_axes)),
                 out_specs=out, check_vma=False,
             )
@@ -310,13 +318,13 @@ class DistributedEngine:
                 z, _ = jax.lax.scan(step, z, None, length=fusion.iters)
                 return z
 
-            return shard_map(
+            return jax.shard_map(
                 mapper, mesh=mesh, in_specs=(in_u, P(None)), out_specs=out,
                 check_vma=False,
             )
 
         u = _device_put(mesh, updates, in_u)
-        w = _device_put(mesh, jnp.asarray(weights, jnp.float32), P(None))
+        w = _device_put(mesh, weights, P(None))
         fn = self._key_get(fusion, updates, n_real, build, u, w)
         return fn(u, w)
 
@@ -544,7 +552,7 @@ class DistributedEngine:
                     block = padded
                 u_dev = _device_put(mesh, block, in_u)
                 dtype = np.dtype(block.dtype)
-            w_dev = _device_put(mesh, jnp.asarray(w_eff, jnp.float32), in_w)
+            w_dev = _device_put(mesh, w_eff, in_w)
             rep.ingest_seconds += time.perf_counter() - t0
             if state is None:
                 host_state = self._stream_state_host(fusion, dim, pdim,
@@ -572,7 +580,7 @@ class DistributedEngine:
                             new = fusion.fold_block(st, u, wv)
                         return tuple(new)
 
-                    return shard_map(
+                    return jax.shard_map(
                         step_fn, mesh=mesh,
                         in_specs=(in_u, in_w) + leaf_specs,
                         out_specs=leaf_specs, check_vma=False,

@@ -42,11 +42,7 @@ from repro.kernels.fused_fusion.kernel import (
     weighted_sum_dequant_pallas,
     weighted_sum_pallas,
 )
-from repro.kernels.robust_fusion.kernel import (
-    coordmedian_pallas,
-    topk_carve_pallas,
-    trimmedmean_pallas,
-)
+from repro.kernels.robust_fusion.kernel import topk_carve_pallas
 from repro.utils.jitcache import CompiledCache, bucket_rows, fusion_cache_key
 
 # fusions whose weighted-sum partial routes through the fused Pallas kernel
@@ -97,11 +93,14 @@ class LocalEngine:
 
     strategy: str = "jnp"        # "jnp" | "pallas"
     memory_cap_bytes: Optional[int] = None  # simulate a memory-limited node
-    interpret: bool = True       # pallas interpret mode (CPU container)
+    # derived, not set: the Pallas kernels are written for the TPU, and
+    # on any other backend they run in the Pallas interpreter
+    interpret: bool = dataclasses.field(init=False)
 
     name: str = "local"
 
     def __post_init__(self):
+        self.interpret = jax.default_backend() != "tpu"
         self.cache = CompiledCache(name=f"local:{self.strategy}")
         # per-THREAD compile accounting: concurrent tenants' rounds share
         # this engine, and one round's warm fold must not read another
@@ -128,10 +127,9 @@ class LocalEngine:
         REDUCIBLE paths (cached executables) it is held only around
         executable invocation — a cold compile builds outside it, so
         one tenant's first-bucket compile never stalls other tenants'
-        folds. The pallas order-statistic and fallback paths compile
-        lazily inside their first call, so a cold round there holds
-        the semaphore through its compile (they have no AOT cache to
-        warm separately)."""
+        folds. The non-reducible paths compile lazily inside their
+        first call, so a cold round there holds the semaphore through
+        its compile (they have no AOT cache to warm separately)."""
         updates = jnp.asarray(updates)
         if weights is None:
             weights = jnp.ones((updates.shape[0],), jnp.float32)
@@ -170,20 +168,11 @@ class LocalEngine:
         if fusion.reducible:
             return self._fuse_reducible_dense(fusion, updates, weights,
                                               device_sem)
-        if self.strategy == "pallas" and fusion.name == "coordmedian":
-            with sem:
-                return self._bounded(
-                    coordmedian_pallas(updates, interpret=self.interpret),
-                    device_sem,
-                )
-        if self.strategy == "pallas" and fusion.name == "trimmedmean":
-            trim = fusion.trim_count(n)
-            with sem:
-                return self._bounded(
-                    trimmedmean_pallas(updates, trim,
-                                       interpret=self.interpret),
-                    device_sem,
-                )
+        # dense order statistics run the fusion's own (XLA) sort under
+        # both strategies: the carve kernel inserts rows in O(n * K) per
+        # coordinate, and at the dense median K = (n-1)//2 the sort's
+        # O(n log n) wins — the kernel serves streamed rounds, whose K
+        # the service's robust_state_budget bounds
         with sem:
             return self._bounded(fusion.fuse(updates, weights), device_sem)
 
@@ -577,6 +566,7 @@ class LocalEngine:
                     (w[:, None] * s).T,
                     q.reshape(c, B, blk).transpose(1, 0, 2)
                      .astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST,
                 ).reshape(-1)[:dim]
                 return ws, jnp.sum(w)
             u = (q.astype(jnp.float32).reshape(c, B, blk)

@@ -109,7 +109,7 @@ from repro.core.monitor import Monitor, MonitorResult
 from repro.core.planner import Plan, Planner
 from repro.core.store import DEFAULT_TENANT, StoreStats, UpdateStore
 from repro.core.workload import Workload, WorkloadClass, classify
-from repro.utils.mem import TPU_V5E, HardwareSpec
+from repro.utils.mem import HardwareSpec, detect_hardware
 from repro.utils.pytree import flat_vector_to_tree, tree_to_flat_vector
 
 PyTree = Any
@@ -163,7 +163,7 @@ class AggregationService:
         self,
         fusion: FusionAlgorithm | str = "fedavg",
         mesh=None,
-        hw: HardwareSpec = TPU_V5E,
+        hw: Optional[HardwareSpec] = None,
         local_strategy: str = "pallas",
         store: Optional[UpdateStore] = None,
         threshold_frac: float = 0.8,
@@ -189,7 +189,9 @@ class AggregationService:
             and async rounds.
           mesh: optional device mesh — enables the distributed (and,
             with a ``pod`` axis, hierarchical) engines.
-          hw: hardware spec for the planner's roofline cost model.
+          hw: hardware spec for the planner's roofline cost model;
+            by default the TPU this process runs on, looked up by
+            ``device_kind`` (``repro.utils.mem.detect_hardware``).
           local_strategy: ``"jnp"`` (baseline) or ``"pallas"`` (fused
             kernel) for the single-chip engine.
           store: the UpdateStore clients write to (``from_store``
@@ -250,6 +252,8 @@ class AggregationService:
             get_fusion(fusion) if isinstance(fusion, str) else fusion
         )
         self.mesh = mesh
+        if hw is None:
+            hw = detect_hardware()
         self.hw = hw
         self.store = store or UpdateStore()
         self.threshold_frac = threshold_frac

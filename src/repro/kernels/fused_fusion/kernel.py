@@ -18,6 +18,10 @@ accumulator if the garbage were NaN/Inf.
 Grid: (ceil(P / PARAM_TILE), ceil(n / CLIENT_TILE)); the output block
 index ignores the client dim, so Pallas keeps it resident in VMEM across
 that dim.
+
+``interpret`` has no default: the caller says whether the kernel compiles
+for the TPU or runs in the Pallas interpreter (CPU). ``LocalEngine``
+derives it from the backend.
 """
 from __future__ import annotations
 
@@ -34,6 +38,10 @@ from repro.utils.jitcache import note_trace
 # 256*2048*4 B (updates tile) + 2048*4 (acc) ~= 2.1 MiB.
 PARAM_TILE = 2048
 CLIENT_TILE = 256
+
+# pin fp32 products on the MXU: at the default precision the TPU may
+# contract fp32 operands as bf16, about 3 significant digits
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _wsum_kernel(w_ref, u_ref, out_ref, *, n_rows, tn, ragged):
@@ -52,7 +60,8 @@ def _wsum_kernel(w_ref, u_ref, out_ref, *, n_rows, tn, ragged):
         ids = jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1)
         w = jnp.where(ids < valid, w, 0.0)
         u = jnp.where(ids.reshape(tn, 1) < valid, u, 0.0)
-    out_ref[...] += jnp.dot(w, u, preferred_element_type=jnp.float32)
+    out_ref[...] += jnp.dot(w, u, preferred_element_type=jnp.float32,
+                            precision=_F32)
 
 
 @functools.partial(
@@ -64,7 +73,7 @@ def weighted_sum_pallas(
     *,
     param_tile: int = PARAM_TILE,
     client_tile: int = CLIENT_TILE,
-    interpret: bool = True,      # CPU container: interpret mode
+    interpret: bool,
 ) -> jnp.ndarray:
     note_trace()
     n, P = updates.shape
@@ -89,18 +98,17 @@ def weighted_sum_pallas(
     return out[0]
 
 
-def _wsum_dequant_kernel(w_ref, q_ref, s_ref, out_ref, *, n_rows, tn, blk,
+def _wsum_dequant_kernel(w_ref, s_ref, q_ref, out_ref, *, n_rows, tn,
                          ragged):
-    """w: (1, TN) fp32; q: (TN, TP) int8; s: (TN, TP//blk) fp32 per-block
-    scales; out: (1, TP) fp32 accumulator.
+    """w: (1, TN) fp32; s: (1, TN) fp32 — each row's scale for THIS
+    parameter tile, which is exactly one quantization block; q: (TN, blk)
+    int8; out: (1, blk) fp32 accumulator.
 
-    Dequantization is folded into the weighted sum: the int8 tile is
-    upcast in VMEM, scaled by its per-block fp32 scales (broadcast over
-    the blk lanes of each quantization block), and fed straight to the
-    same (1, TN) x (TN, TP) dot as the dense kernel — the fp32 update
-    matrix never exists in HBM, only one (TN, TP) tile at a time in
-    VMEM. Ragged client tiles mask both the weight lane and the
-    dequantized rows (scale lanes past n_rows are unspecified VMEM, so
+    Dequantization is folded into the weighted sum: with one scale per
+    row per tile, w[i] * s[i] * q[i, p] is a (1, TN) x (TN, blk) dot of
+    the scaled weight row against the upcast int8 tile — the fp32 update
+    matrix never exists in HBM. Ragged client tiles mask both the weight
+    lane and the code rows (lanes past n_rows are unspecified VMEM, so
     0 * garbage could still be NaN)."""
     j = pl.program_id(1)
 
@@ -108,22 +116,19 @@ def _wsum_dequant_kernel(w_ref, q_ref, s_ref, out_ref, *, n_rows, tn, blk,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    q = q_ref[...].astype(jnp.float32)          # (tn, tp)
-    s = s_ref[...]                              # (tn, tp // blk)
-    w = w_ref[...]
-    tp = q.shape[1]
-    u = (q.reshape(tn, tp // blk, blk) * s[:, :, None]).reshape(tn, tp)
+    q = q_ref[...].astype(jnp.float32)          # (tn, blk)
+    w = w_ref[...] * s_ref[...]
     if ragged:
         valid = n_rows - j * tn
         ids = jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1)
         w = jnp.where(ids < valid, w, 0.0)
-        u = jnp.where(ids.reshape(tn, 1) < valid, u, 0.0)
-    out_ref[...] += jnp.dot(w, u, preferred_element_type=jnp.float32)
+        q = jnp.where(ids.reshape(tn, 1) < valid, q, 0.0)
+    out_ref[...] += jnp.dot(w, q, preferred_element_type=jnp.float32,
+                            precision=_F32)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block", "param_tile", "client_tile",
-                              "interpret")
+    jax.jit, static_argnames=("block", "client_tile", "interpret")
 )
 def weighted_sum_dequant_pallas(
     codes: jnp.ndarray,          # (n, Pq) int8, Pq a multiple of block
@@ -131,12 +136,17 @@ def weighted_sum_dequant_pallas(
     weights: jnp.ndarray,        # (n,) fp32
     *,
     block: int = 2048,           # quantization block (compress.BLOCK)
-    param_tile: int = PARAM_TILE,
     client_tile: int = CLIENT_TILE,
-    interpret: bool = True,      # CPU container: interpret mode
+    interpret: bool,
 ) -> jnp.ndarray:
     """Weighted sum of block-quantized rows with the dequant scales
     folded in-kernel: out[p] = sum_i w[i] * s[i, p//block] * q[i, p].
+
+    The parameter tile is one quantization block, so ``block`` must be a
+    multiple of 128 (the TPU lane width) for the compiled kernel. The
+    scales enter transposed as (Pq // block, 1, n): each grid cell's
+    (1, TN) scale row then has lane-aligned block dims, which a (TN, 1)
+    slice of the (n, Pq // block) matrix does not.
 
     Returns the (Pq,) fp32 weighted sum over the PADDED parameter axis
     (codes past the logical dim are zero by the CompressedUpdate
@@ -145,27 +155,24 @@ def weighted_sum_dequant_pallas(
     n, Pq = codes.shape
     if Pq % block:
         raise ValueError(f"codes width {Pq} not a multiple of block {block}")
+    nb = Pq // block
     tn = min(client_tile, n)
-    # the param tile must cover whole quantization blocks so each grid
-    # cell sees its own scales; Pq is always a multiple of block
-    tp = min(max(block, (param_tile // block) * block), Pq)
     w2 = weights.astype(jnp.float32).reshape(1, n)
+    s3 = scales.astype(jnp.float32).T.reshape(nb, 1, n)
 
     kernel = functools.partial(
-        _wsum_dequant_kernel, n_rows=n, tn=tn, blk=block,
-        ragged=bool(n % tn),
+        _wsum_dequant_kernel, n_rows=n, tn=tn, ragged=bool(n % tn),
     )
-    m = tp // block
     out = pl.pallas_call(
         kernel,
-        grid=(pl.cdiv(Pq, tp), pl.cdiv(n, tn)),
+        grid=(nb, pl.cdiv(n, tn)),
         in_specs=[
             pl.BlockSpec((1, tn), lambda i, j: (0, j)),
-            pl.BlockSpec((tn, tp), lambda i, j: (j, i)),
-            pl.BlockSpec((tn, m), lambda i, j: (j, i)),
+            pl.BlockSpec((None, 1, tn), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((tn, block), lambda i, j: (j, i)),
         ],
-        out_specs=pl.BlockSpec((1, tp), lambda i, j: (0, i)),
+        out_specs=pl.BlockSpec((1, block), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Pq), jnp.float32),
         interpret=interpret,
-    )(w2, codes, scales)
+    )(w2, s3, codes)
     return out[0]

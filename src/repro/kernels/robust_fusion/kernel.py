@@ -1,11 +1,29 @@
-"""Pallas TPU kernel: tiled coordinate-wise median / trimmed mean.
+"""Pallas TPU kernel: the streamed top-k carve for exact trimmed mean /
+coordinate-wise median.
 
-Robust fusions need every client's value per coordinate, so the tiling is
-columnar: each grid step loads a (n x PARAM_TILE) strip into VMEM, sorts
-along the client axis in-register, and emits the statistic for that strip.
-One HBM pass; n is bounded by VMEM (n * PARAM_TILE * 4 bytes <= ~8 MiB for
-the default tile), which is exactly the VMEM_RESIDENT workload class —
-larger n goes through the distributed engine's all-to-all path instead.
+The carry per parameter tile is a running column sum plus the K largest
+(``topk``) and K smallest (``botk``) values seen per coordinate, both
+ascending along the K axis. Each grid step loads one (c, TP) strip of a
+block and inserts its rows one at a time, without a sort (Mosaic has no
+sort lowering): inserting x into the ascending top-K t keeps
+
+    t'[k] = max(t[k], min(x, t[k+1]))      (t[K] = +inf)
+
+and into the ascending bottom-K b keeps
+
+    b'[k] = min(b[k], max(x, b[k-1]))      (b[-1] = -inf)
+
+— compare-exchanges on whole (K, TP) tiles, O(c * K) per coordinate.
+Padding rows (validity 0) enter as -inf / +inf and never survive; their
+sum contribution is masked to 0.
+
+The parameter axis is independent per column, so a ragged final tile
+needs no host-side pad: columns past P compute on unspecified VMEM and
+their writes are dropped.
+
+Dense (non-streamed) order statistics do not use this kernel: at the
+median K = (n-1)//2, and the O(n * K) insertion loses to XLA's sort —
+``LocalEngine.fuse`` runs the fusion's own sort for them.
 """
 from __future__ import annotations
 
@@ -14,136 +32,80 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 PARAM_TILE = 1024
 
 
-def _trimmed_kernel(u_ref, out_ref, *, trim: int):
-    u = u_ref[...].astype(jnp.float32)          # (n, TP)
-    n = u.shape[0]
-    s = jnp.sort(u, axis=0)
-    if trim > 0:
-        s = jax.lax.slice_in_dim(s, trim, n - trim, axis=0)
-    out_ref[...] = jnp.mean(s, axis=0, keepdims=True)
-
-
-def _exact_median_kernel(u_ref, out_ref):
-    u = u_ref[...].astype(jnp.float32)
-    n = u.shape[0]
-    s = jnp.sort(u, axis=0)
-    mid = n // 2
-    if n % 2 == 1:
-        med = s[mid]
-    else:
-        med = 0.5 * (s[mid - 1] + s[mid])
-    out_ref[...] = med[None, :]
-
-
-@functools.partial(jax.jit, static_argnames=("param_tile", "interpret"))
-def coordmedian_pallas(updates: jnp.ndarray, *, param_tile: int = PARAM_TILE,
-                       interpret: bool = True) -> jnp.ndarray:
-    n, P = updates.shape
-    tp = min(param_tile, P)
-    p_pad = (-P) % tp
-    if p_pad:
-        updates = jnp.pad(updates, ((0, 0), (0, p_pad)))
-    PP = updates.shape[1]
-    out = pl.pallas_call(
-        _exact_median_kernel,
-        grid=(PP // tp,),
-        in_specs=[pl.BlockSpec((n, tp), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, tp), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, PP), jnp.float32),
-        interpret=interpret,
-    )(updates)
-    return out[0, :P]
+def _shifted(t, fill, up: bool):
+    """Rows of ``t`` moved one place (up: row k <- row k+1), with the
+    vacated row set to ``fill``."""
+    edge = jnp.full_like(t[:1], fill)
+    if t.shape[0] == 1:
+        return edge
+    if up:
+        return jnp.concatenate([t[1:], edge], axis=0)
+    return jnp.concatenate([edge, t[:-1]], axis=0)
 
 
 def _carve_kernel(v_ref, u_ref, s_ref, t_ref, b_ref,
-                  so_ref, to_ref, bo_ref):
-    """Merge one (c, TP) block strip into the carried running sum and
-    per-coordinate top-K / bottom-K buffers. ``v_ref`` is the (1, c)
-    validity row — 0 marks ragged-tail padding rows, which are masked to
-    -/+inf so the sort carries them straight out of the kept slices.
-    One sort per buffer per strip; one HBM pass over the block."""
-    u = u_ref[...].astype(jnp.float32)                     # (c, TP)
-    vm = v_ref[...].reshape(-1, 1) > 0                     # (c, 1)
-    so_ref[...] = s_ref[...] + jnp.sum(
-        jnp.where(vm, u, 0.0), axis=0, keepdims=True)
-    k_cap = t_ref.shape[0]
-    m = k_cap + u.shape[0]
-    hi = jnp.sort(jnp.concatenate(
-        [t_ref[...], jnp.where(vm, u, -jnp.inf)], axis=0), axis=0)
-    to_ref[...] = jax.lax.slice_in_dim(hi, m - k_cap, m, axis=0)
-    lo = jnp.sort(jnp.concatenate(
-        [b_ref[...], jnp.where(vm, u, jnp.inf)], axis=0), axis=0)
-    bo_ref[...] = jax.lax.slice_in_dim(lo, 0, k_cap, axis=0)
+                  so_ref, to_ref, bo_ref, x_ref):
+    """v: (c,) SMEM validity; u: (c, TP); s: (1, TP) running sum;
+    t / b: (K, TP) ascending top-K / bottom-K. Outputs mirror s, t, b.
+    x: (c, TP) fp32 scratch — the strip upcast once, because Mosaic
+    loads single rows at a dynamic index only from 32-bit arrays."""
+    so_ref[...] = s_ref[...]
+    to_ref[...] = t_ref[...]
+    bo_ref[...] = b_ref[...]
+    x_ref[...] = u_ref[...].astype(jnp.float32)
+
+    def insert(r, carry):
+        x = x_ref[pl.ds(r, 1), :]                           # (1, TP)
+        ok = v_ref[r] > 0
+        so_ref[...] += jnp.where(ok, x, 0.0)
+        t = to_ref[...]
+        hi = jnp.where(ok, x, -jnp.inf)
+        to_ref[...] = jnp.maximum(
+            t, jnp.minimum(hi, _shifted(t, jnp.inf, up=True)))
+        b = bo_ref[...]
+        lo = jnp.where(ok, x, jnp.inf)
+        bo_ref[...] = jnp.minimum(
+            b, jnp.maximum(lo, _shifted(b, -jnp.inf, up=False)))
+        return carry
+
+    jax.lax.fori_loop(0, u_ref.shape[0], insert, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("param_tile", "interpret"))
 def topk_carve_pallas(block: jnp.ndarray, valid: jnp.ndarray,
                       ssum: jnp.ndarray, topk: jnp.ndarray,
                       botk: jnp.ndarray, *, param_tile: int = PARAM_TILE,
-                      interpret: bool = True):
+                      interpret: bool):
     """Streaming fold for exact trimmed mean / median: merge a (c, P)
     block into carry (ssum (P,), topk (K, P), botk (K, P)). ``valid``
-    (c,) is 0/1 (0 = padded row). Returns the updated carry triple."""
+    (c,) is 0/1 (0 = padded row). Returns the updated carry triple.
+    ``interpret`` has no default: compiled on the TPU, the Pallas
+    interpreter on the CPU (``LocalEngine`` derives it)."""
     c, P = block.shape
     k_cap = topk.shape[0]
     tp = min(param_tile, P)
-    p_pad = (-P) % tp
-    if p_pad:
-        # zero-pad the param axis; padded columns produce garbage carry
-        # values that the [:P] slices below discard
-        block = jnp.pad(block, ((0, 0), (0, p_pad)))
-        ssum = jnp.pad(ssum, (0, p_pad))
-        topk = jnp.pad(topk, ((0, 0), (0, p_pad)))
-        botk = jnp.pad(botk, ((0, 0), (0, p_pad)))
-    PP = P + p_pad
+    strip = pl.BlockSpec((1, tp), lambda i: (0, i))
+    kbuf = pl.BlockSpec((k_cap, tp), lambda i: (0, i))
     so, to, bo = pl.pallas_call(
         _carve_kernel,
-        grid=(PP // tp,),
+        grid=(pl.cdiv(P, tp),),
         in_specs=[
-            pl.BlockSpec((1, c), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((c, tp), lambda i: (0, i)),
-            pl.BlockSpec((1, tp), lambda i: (0, i)),
-            pl.BlockSpec((k_cap, tp), lambda i: (0, i)),
-            pl.BlockSpec((k_cap, tp), lambda i: (0, i)),
+            strip, kbuf, kbuf,
         ],
-        out_specs=[
-            pl.BlockSpec((1, tp), lambda i: (0, i)),
-            pl.BlockSpec((k_cap, tp), lambda i: (0, i)),
-            pl.BlockSpec((k_cap, tp), lambda i: (0, i)),
-        ],
+        out_specs=[strip, kbuf, kbuf],
+        scratch_shapes=[pltpu.VMEM((c, tp), jnp.float32)],
         out_shape=[
-            jax.ShapeDtypeStruct((1, PP), jnp.float32),
-            jax.ShapeDtypeStruct((k_cap, PP), jnp.float32),
-            jax.ShapeDtypeStruct((k_cap, PP), jnp.float32),
+            jax.ShapeDtypeStruct((1, P), jnp.float32),
+            jax.ShapeDtypeStruct((k_cap, P), jnp.float32),
+            jax.ShapeDtypeStruct((k_cap, P), jnp.float32),
         ],
         interpret=interpret,
-    )(valid.astype(jnp.float32).reshape(1, c), block,
-      ssum.reshape(1, PP), topk, botk)
-    return so[0, :P], to[:, :P], bo[:, :P]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("trim", "param_tile", "interpret")
-)
-def trimmedmean_pallas(updates: jnp.ndarray, trim: int,
-                       *, param_tile: int = PARAM_TILE,
-                       interpret: bool = True) -> jnp.ndarray:
-    n, P = updates.shape
-    tp = min(param_tile, P)
-    p_pad = (-P) % tp
-    if p_pad:
-        updates = jnp.pad(updates, ((0, 0), (0, p_pad)))
-    PP = updates.shape[1]
-    out = pl.pallas_call(
-        functools.partial(_trimmed_kernel, trim=trim),
-        grid=(PP // tp,),
-        in_specs=[pl.BlockSpec((n, tp), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, tp), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, PP), jnp.float32),
-        interpret=interpret,
-    )(updates)
-    return out[0, :P]
+    )(valid.astype(jnp.float32), block, ssum.reshape(1, P), topk, botk)
+    return so[0], to, bo

@@ -1,18 +1,12 @@
-"""Jit'd public wrappers for the robust-fusion kernels."""
+"""Public wrappers for the robust-fusion carve kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.robust_fusion.kernel import (
-    coordmedian_pallas,
-    topk_carve_pallas,
-    trimmedmean_pallas,
-)
+from repro.kernels.robust_fusion.kernel import topk_carve_pallas
 from repro.kernels.robust_fusion.ref import topk_carve_ref
 
 __all__ = [
-    "coordmedian_pallas",
-    "trimmedmean_pallas",
     "topk_carve_pallas",
     "topk_carve_ref",
     "carve_stream_dense",
@@ -20,9 +14,9 @@ __all__ = [
 
 
 def carve_stream_dense(updates, trim: int, *, chunk: int = 8,
-                       use_pallas: bool = True, interpret: bool = True):
+                       interpret: bool):
     """Dense-parity harness: stream a dense (n, P) matrix through the
-    carve fold in (chunk, P) blocks and finalize. Must equal
+    carve kernel in (chunk, P) blocks and finalize. Must equal
     ``trimmedmean_ref(updates, trim)`` (trim = (n-1)//2 gives the
     median) — used by tests to pin the streamed path to the oracle."""
     n, p = updates.shape
@@ -32,15 +26,14 @@ def carve_stream_dense(updates, trim: int, *, chunk: int = 8,
     ssum = jnp.zeros((p,), jnp.float32)
     topk = jnp.full((k_cap, p), -jnp.inf, jnp.float32)
     botk = jnp.full((k_cap, p), jnp.inf, jnp.float32)
-    fold = topk_carve_pallas if use_pallas else topk_carve_ref
-    kw = {"interpret": interpret} if use_pallas else {}
     for i in range(0, n, chunk):
         blk = updates[i: i + chunk]
         rows = blk.shape[0]
         if rows < chunk:  # ragged tail: zero rows masked out by valid
             blk = jnp.pad(blk, ((0, chunk - rows), (0, 0)))
         valid = (jnp.arange(chunk) < rows).astype(jnp.float32)
-        ssum, topk, botk = fold(blk, valid, ssum, topk, botk, **kw)
+        ssum, topk, botk = topk_carve_pallas(blk, valid, ssum, topk, botk,
+                                             interpret=interpret)
     s = ssum
     if trim > 0:
         s = s - jnp.sum(topk[k_cap - trim:], axis=0)

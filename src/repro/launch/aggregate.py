@@ -58,6 +58,7 @@ from repro.core import (
     Workload,
     classify,
 )
+from repro.utils.jitcache import enable_persistent_cache
 from repro.utils.mem import bytes_to_human
 
 
@@ -104,8 +105,10 @@ def main():
                          "(per tenant)")
     ap.add_argument("--fusion", default="fedavg",
                     help="fusion algorithm (repro.core.fusion.REGISTRY)")
-    ap.add_argument("--local-strategy", default="jnp",
-                    help='single-chip engine: "jnp" or "pallas"')
+    ap.add_argument("--local-strategy", default=None,
+                    choices=["jnp", "pallas"],
+                    help="single-chip engine (default: the service's, "
+                         "the fused Pallas kernels)")
     ap.add_argument("--compress", action="store_true",
                     help="quantize client writes to int8 codes + fp32 "
                          "per-block scales (error feedback per tenant); "
@@ -153,13 +156,15 @@ def main():
                     help="over-budget writes: reject (raise) or evict "
                          "the tenant's oldest resident updates")
     args = ap.parse_args()
+    enable_persistent_cache()
 
     spec = CNN_SUITE[args.model]
     n_params = spec.num_params
     store = UpdateStore()
+    strategy = ({"local_strategy": args.local_strategy}
+                if args.local_strategy else {})
     svc = AggregationService(
-        fusion=args.fusion, store=store,
-        local_strategy=args.local_strategy,
+        fusion=args.fusion, store=store, **strategy,
         threshold_frac=args.threshold_frac, monitor_timeout=args.timeout,
         adaptive=args.adaptive, cost_bias=args.cost_bias,
         compress=args.compress,

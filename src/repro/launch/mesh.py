@@ -9,9 +9,18 @@ the real single CPU device).
 """
 from __future__ import annotations
 
-from jax.sharding import Mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
-from repro.utils.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types: the engines place data
+    with ``NamedSharding`` and ``shard_map``, not sharding-in-types
+    (``jax.make_mesh`` defaults to Explicit axes)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(AxisType.Auto,) * len(axis_names),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import AggregationService, UpdateStore
 from repro.fl import EdgeAggregatorServer
 from repro.serving import HttpStoreClient
+from repro.utils.jitcache import enable_persistent_cache
 from repro.utils.mem import bytes_to_human
 from repro.workload import (
     FixedSize,
@@ -93,10 +94,11 @@ def main():
                     help="listen port (0: ephemeral)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_persistent_cache()
 
     store = UpdateStore()
     svc = AggregationService(
-        fusion=args.fusion, store=store, local_strategy="jnp",
+        fusion=args.fusion, store=store,
         threshold_frac=args.threshold_frac,
         monitor_timeout=args.timeout, compress=args.compress,
     )

@@ -217,7 +217,6 @@ def make_aggregate_step(mesh: Mesh, n_clients: int):
     the data axes (half an all-reduce's ring traffic, and no chip ever
     materializes the full fused model). Leaves whose leading dim doesn't
     divide fall back to ``psum``."""
-    from repro.utils.compat import shard_map
     from repro.launch.mesh import data_axis_names, n_data_shards
 
     dp = data_axis_names(mesh)
@@ -342,7 +341,7 @@ def make_aggregate_step(mesh: Mesh, n_clients: int):
 
             return jax.tree_util.tree_map(leaf_fuse, u_tree, scatter_tree)
 
-        step = shard_map(
+        step = jax.shard_map(
             local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )

@@ -27,7 +27,6 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.utils.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers.mlp import MLPParams, init_mlp, mlp
@@ -223,7 +222,7 @@ def _moe_a2a(p, x, top_k, cf, rules):
         aux = jax.lax.pmean(aux, mesh.axis_names)
         return out.reshape(bl, tl, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),

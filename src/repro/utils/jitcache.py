@@ -24,11 +24,38 @@ reuse term (warm engines are costed below cold ones).
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
 import time
+from pathlib import Path
 from typing import Callable, Dict, Hashable, Tuple
 
 import jax
+
+# the checkout root (this file is src/repro/utils/jitcache.py)
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+# -- persistent compilation cache ---------------------------------------------
+
+
+def enable_persistent_cache() -> str:
+    """Keep compiled programs across processes; returns the directory.
+
+    Entry points call this at start-up, never at import. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
+    other path is set here; otherwise the cache is ``.jax_cache/`` at the
+    checkout root. The path is fixed — never a temp name, pid or time —
+    because a directory that moves is never hit again. Every compile is
+    kept, however short: the kernels compile in well under JAX's default
+    one-second floor."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
 
 # -- trace accounting ---------------------------------------------------------
 
@@ -213,6 +240,13 @@ class CompiledCache:
         finally:
             self._release(key)
         return jitted
+
+    def executables(self) -> Dict[Hashable, Callable]:
+        """Snapshot of the cached executables by key. ``get`` entries are
+        ``jax.stages.Compiled``, whose ``as_text()`` is the compiled HLO
+        (a compiled Pallas kernel shows as ``tpu_custom_call``)."""
+        with self._lock:
+            return {k: e.fn for k, e in self._entries.items()}
 
     def clear(self) -> None:
         with self._lock:

@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+
 
 @dataclasses.dataclass(frozen=True)
 class HardwareSpec:
@@ -37,6 +39,28 @@ TPU_V5E = HardwareSpec(
     ici_bw_per_link=50e9,
     ici_links=4,
 )
+
+# jax.devices()[0].device_kind -> spec. A TPU kind missing here is an
+# error, never a silent v5e.
+HARDWARE_BY_KIND = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def detect_hardware() -> HardwareSpec:
+    """The spec of the TPU this process runs on, looked up by
+    ``device_kind``. Off the TPU (the CPU test backend) the planner
+    models the v5e, the deployment target."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    try:
+        return HARDWARE_BY_KIND[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for TPU kind {dev.device_kind!r}; add one "
+            "to repro.utils.mem.HARDWARE_BY_KIND"
+        ) from None
 
 
 def bytes_to_human(n: float) -> str:

@@ -25,7 +25,7 @@ from repro.core import (
     Workload,
     get_fusion,
 )
-from repro.utils.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 RNG = np.random.default_rng(31)
 

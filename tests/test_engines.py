@@ -47,7 +47,7 @@ def test_local_pallas_matches_jnp(fusion, data):
 @pytest.mark.parametrize("fusion", ALL_FUSIONS, ids=lambda f: f.name)
 def test_distributed_1dev_matches_local(fusion, data):
     u, w = data
-    from repro.utils.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     a = np.asarray(LocalEngine(strategy="jnp").fuse(fusion, u, w))
     b = np.asarray(DistributedEngine(mesh=mesh).fuse(fusion, u, w))
@@ -87,7 +87,7 @@ _SUBPROC = textwrap.dedent("""
     from repro.core import DistributedEngine, LocalEngine
     from repro.core.fusion import (FedAvg, IterAvg, ClippedAvg, CoordMedian,
                                    TrimmedMean, Krum, Zeno, GeometricMedian)
-    from repro.utils.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     rng = np.random.default_rng(1)
     u = rng.normal(size=(13, 257)).astype(np.float32)
@@ -116,3 +116,71 @@ def test_multi_device_equivalence_subprocess():
              "HOME": "/root", "JAX_PLATFORMS": "cpu"},
     )
     assert "MULTI_DEVICE_OK" in r.stdout, r.stderr[-3000:]
+
+
+# -- backend-derived settings ---------------------------------------------------
+
+
+def test_local_engine_interprets_off_tpu(data):
+    """The kernels compile only for the TPU: anywhere else the engine
+    derives interpret mode, and the fold's HLO holds no TPU kernel."""
+    u, w = data
+    assert jax.default_backend() != "tpu"
+    eng = LocalEngine(strategy="pallas")
+    assert eng.interpret is True
+    eng.fuse(FedAvg(), u, w)
+    (fold,) = eng.cache.executables().values()
+    assert "tpu_custom_call" not in fold.as_text()
+
+
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", "tpu-v5e"),          # off the TPU: the modeled target
+    ("tpu", "TPU v5 lite", "tpu-v5e"),
+    ("tpu", "TPU v4", None),            # unknown kind: an error, not v5e
+])
+def test_service_hardware_from_device_kind(monkeypatch, platform, kind,
+                                           want):
+    from repro.core import AggregationService
+    from repro.utils import mem
+
+    monkeypatch.setattr(mem.jax, "devices",
+                        lambda: [_Device(platform, kind)])
+    if want is None:
+        with pytest.raises(ValueError, match="TPU v4"):
+            AggregationService()
+    else:
+        assert AggregationService().hw.name == want
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_persistent_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache is
+    the checkout's fixed .jax_cache/. Every compile is kept."""
+    import os
+
+    from repro.utils.jitcache import enable_persistent_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        if env_dir:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            # what JAX itself reads from the variable at start-up
+            jax.config.update(keys[0], str(tmp_path))
+            want = str(tmp_path)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), ".jax_cache")
+        assert enable_persistent_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

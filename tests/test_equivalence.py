@@ -22,7 +22,7 @@ from repro.core import (
     UpdateStore,
 )
 from repro.core.fusion import REGISTRY, get_fusion
-from repro.utils.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 RNG = np.random.default_rng(23)
 
@@ -173,7 +173,7 @@ def test_distributed_stream_multidevice_subprocess():
         import numpy as np
         from repro.core import DistributedEngine, LocalEngine
         from repro.core.fusion import get_fusion
-        from repro.utils.compat import make_mesh
+        from repro.launch.mesh import make_mesh
 
         rng = np.random.default_rng(7)
         n, p, chunk = 21, 266, 6

@@ -1,5 +1,6 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp ref.py oracles
-(interpret=True executes the kernel body on CPU)."""
+(interpret=True executes the kernel body on CPU; tests/test_tpu_compile.py
+compiles the same kernels for the TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,10 +23,8 @@ from repro.kernels.fused_fusion.ref import (
     weighted_sum_dequant_ref,
     weighted_sum_ref,
 )
-from repro.kernels.robust_fusion.kernel import (
-    coordmedian_pallas,
-    trimmedmean_pallas,
-)
+from repro.core.fusion.robust import CoordMedian, TrimmedMean
+from repro.core.local import LocalEngine
 from repro.kernels.robust_fusion.ref import coordmedian_ref, trimmedmean_ref
 
 RNG = np.random.default_rng(7)
@@ -40,7 +39,7 @@ RNG = np.random.default_rng(7)
 def test_weighted_sum_shapes_dtypes(n, p, dtype):
     u = jnp.asarray(RNG.normal(size=(n, p)).astype(np.float32)).astype(dtype)
     w = jnp.asarray(RNG.uniform(1, 4, size=(n,)).astype(np.float32))
-    out = weighted_sum_pallas(u, w)
+    out = weighted_sum_pallas(u, w, interpret=True)
     ref = weighted_sum_ref(u, w)
     tol = 2e-5 if dtype == np.float32 else 2e-2
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol * 10)
@@ -50,7 +49,8 @@ def test_weighted_sum_shapes_dtypes(n, p, dtype):
 def test_weighted_sum_tile_sweep(pt, ct):
     u = jnp.asarray(RNG.normal(size=(40, 700)).astype(np.float32))
     w = jnp.asarray(RNG.uniform(1, 4, size=(40,)).astype(np.float32))
-    out = weighted_sum_pallas(u, w, param_tile=pt, client_tile=ct)
+    out = weighted_sum_pallas(u, w, param_tile=pt, client_tile=ct,
+                              interpret=True)
     np.testing.assert_allclose(out, weighted_sum_ref(u, w), rtol=2e-5,
                                atol=1e-4)
 
@@ -58,10 +58,11 @@ def test_weighted_sum_tile_sweep(pt, ct):
 def test_fedavg_iteravg_ops():
     u = jnp.asarray(RNG.normal(size=(9, 333)).astype(np.float32))
     w = jnp.asarray(RNG.uniform(1, 9, size=(9,)).astype(np.float32))
-    np.testing.assert_allclose(fedavg_fused(u, w), fedavg_ref(u, w),
-                               rtol=2e-5, atol=1e-6)
+    np.testing.assert_allclose(fedavg_fused(u, w, interpret=True),
+                               fedavg_ref(u, w), rtol=2e-5, atol=1e-6)
     np.testing.assert_allclose(
-        iteravg_fused(u), np.asarray(u).mean(0), rtol=2e-5, atol=1e-5
+        iteravg_fused(u, interpret=True), np.asarray(u).mean(0),
+        rtol=2e-5, atol=1e-5
     )
 
 
@@ -72,7 +73,7 @@ def test_weighted_sum_property(n, p, seed):
     u = jnp.asarray(r.normal(size=(n, p)).astype(np.float32))
     w = jnp.asarray(r.uniform(0, 3, size=(n,)).astype(np.float32))
     np.testing.assert_allclose(
-        weighted_sum_pallas(u, w), weighted_sum_ref(u, w),
+        weighted_sum_pallas(u, w, interpret=True), weighted_sum_ref(u, w),
         rtol=1e-4, atol=1e-3,
     )
 
@@ -100,7 +101,7 @@ def _quantized(n, p, block, rng):
 ])
 def test_weighted_sum_dequant_parity(n, p, block):
     q, s, w = _quantized(n, p, block, np.random.default_rng(n * 1000 + p))
-    out = weighted_sum_dequant_pallas(q, s, w, block=block)
+    out = weighted_sum_dequant_pallas(q, s, w, block=block, interpret=True)
     ref = weighted_sum_dequant_ref(q, s, w, block=block)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=1e-4)
 
@@ -115,8 +116,8 @@ def test_weighted_sum_dequant_matches_dense_kernel():
     dense = (np.asarray(q, np.float32).reshape(19, nb, blk)
              * np.asarray(s)[:, :, None]).reshape(19, -1)
     np.testing.assert_allclose(
-        weighted_sum_dequant_pallas(q, s, w),
-        weighted_sum_pallas(jnp.asarray(dense), w),
+        weighted_sum_dequant_pallas(q, s, w, interpret=True),
+        weighted_sum_pallas(jnp.asarray(dense), w, interpret=True),
         rtol=2e-5, atol=1e-4,
     )
 
@@ -124,7 +125,7 @@ def test_weighted_sum_dequant_matches_dense_kernel():
 def test_fedavg_fused_dequant_op():
     rng = np.random.default_rng(5)
     q, s, w = _quantized(9, 3000, 1024, rng)
-    out = fedavg_fused_dequant(q, s, w, block=1024)
+    out = fedavg_fused_dequant(q, s, w, block=1024, interpret=True)
     ref = weighted_sum_dequant_ref(q, s, w, block=1024) / jnp.sum(w)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=1e-5)
 
@@ -135,29 +136,32 @@ def test_weighted_sum_dequant_property(n, nb, seed):
     block = 128
     q, s, w = _quantized(n, nb * block, block, np.random.default_rng(seed))
     np.testing.assert_allclose(
-        weighted_sum_dequant_pallas(q, s, w, block=block),
+        weighted_sum_dequant_pallas(q, s, w, block=block, interpret=True),
         weighted_sum_dequant_ref(q, s, w, block=block),
         rtol=1e-4, atol=1e-3,
     )
 
 
-# -- robust_fusion ------------------------------------------------------------
+# -- robust_fusion: dense rounds on the pallas strategy -----------------------
+# (the dense order statistics run the fusion's sort, not a kernel — see
+# LocalEngine.fuse; the carve kernel is swept in test_robust_stream.py)
 
 
 @pytest.mark.parametrize("n,p", [(3, 64), (8, 1025), (17, 4096), (33, 100)])
 def test_coordmedian_sweep(n, p):
     u = jnp.asarray(RNG.normal(size=(n, p)).astype(np.float32))
-    np.testing.assert_allclose(
-        coordmedian_pallas(u), coordmedian_ref(u), rtol=1e-6, atol=1e-6
-    )
+    out = LocalEngine(strategy="pallas").fuse(CoordMedian(), u, None)
+    np.testing.assert_allclose(out, coordmedian_ref(u), rtol=1e-6, atol=1e-6)
 
 
 @pytest.mark.parametrize("n,trim", [(9, 0), (9, 2), (20, 5)])
 def test_trimmedmean_sweep(n, trim):
     u = jnp.asarray(RNG.normal(size=(n, 513)).astype(np.float32))
+    fusion = TrimmedMean(beta=trim / n)
+    assert fusion.trim_count(n) == trim
     np.testing.assert_allclose(
-        trimmedmean_pallas(u, trim), trimmedmean_ref(u, trim),
-        rtol=1e-5, atol=1e-5,
+        LocalEngine(strategy="pallas").fuse(fusion, u, None),
+        trimmedmean_ref(u, trim), rtol=1e-5, atol=1e-5,
     )
 
 

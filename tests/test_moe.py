@@ -81,7 +81,7 @@ _SUBPROC = textwrap.dedent("""
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(4, 16, d)) * 0.5, jnp.float32)
     o_ref, _ = _moe_scatter(p, x, k, 8.0)
-    from repro.utils.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ("data", "model"))
     rules = AxisRules(mesh=mesh, rules={"batch": ("data",),
                                         "seq": ("model",),

@@ -24,7 +24,7 @@ from repro.core.distributed import DistributedEngine
 from repro.core.fusion import REGISTRY, get_fusion
 from repro.kernels.fused_fusion.kernel import weighted_sum_pallas
 from repro.utils import jitcache
-from repro.utils.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 RNG = np.random.default_rng(11)
 
@@ -188,7 +188,8 @@ def test_pallas_ragged_no_full_matrix_pad():
 
     with mock.patch.object(jax.numpy, "pad", side_effect=spy_pad):
         # fresh shape + tiles => forces a trace through the wsum path
-        out = weighted_sum_pallas(u, w, param_tile=256, client_tile=8)
+        out = weighted_sum_pallas(u, w, param_tile=256, client_tile=8,
+                                  interpret=True)
     assert not our_pads, f"kernel wrapper pad-copied: {our_pads}"
     ref = jnp.einsum("np,n->p", u, w)
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)

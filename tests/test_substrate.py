@@ -171,7 +171,7 @@ def test_hlo_while_trip_multiplication():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.utils.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("x",))
 
     def body(c, _):
@@ -181,9 +181,8 @@ def test_hlo_while_trip_multiplication():
         out, _ = jax.lax.scan(body, x, None, length=5)
         return out
 
-    from repro.utils.compat import shard_map
-    sfn = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                    check_vma=False)
+    sfn = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                        check_vma=False)
     compiled = jax.jit(sfn).lower(
         jax.ShapeDtypeStruct((128,), jnp.float32)
     ).compile()
