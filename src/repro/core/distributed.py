@@ -32,7 +32,6 @@ never the dense (n, P) matrix.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import threading
 import time
@@ -47,6 +46,7 @@ from repro.core.compress import BLOCK, CompressedBlock
 from repro.core.fusion.base import FusionAlgorithm
 from repro.core.fusion.robust import GeometricMedian, Krum, Zeno
 from repro.core.local import StreamReport, _check_scale
+from repro.utils import spans
 from repro.utils.jitcache import CompiledCache, bucket_rows, fusion_cache_key
 
 
@@ -460,8 +460,7 @@ class DistributedEngine:
             in_u = P(None, self.param_axis)
             in_w = P(None)
         rep = StreamReport()
-        sem = device_sem if device_sem is not None \
-            else contextlib.nullcontext()
+        sem = spans.DeviceSlot(device_sem)
         it = iter(blocks)
         steps: dict = {}   # payload dtype -> cached fold step
         deqs: dict = {}    # (Pq, blk) -> cached dequant executable
@@ -477,126 +476,127 @@ class DistributedEngine:
             except StopIteration:
                 break
             rep.ingest_seconds += time.perf_counter() - t0
-            block, w = item[0], item[1]
-            scale = _check_scale(item[2]) if len(item) > 2 else None
-            if scale is not None and not weighted:
-                raise ValueError(
-                    f"{fusion.name}: per-row staleness scales are "
-                    "unsupported — order statistics cannot discount rows"
-                )
-            compressed = isinstance(block, CompressedBlock)
-            rows = block.rows if compressed else block.shape[0]
-            bdim = block.dim if compressed else block.shape[1]
-            if chunk is None:
-                dim = bdim
-                chunk = int(chunk_rows) if chunk_rows else rows
-                rep.chunk_rows = chunk
-                pc = chunk + (-chunk) % self._n_client_shards
-                pdim = dim + (
-                    (-dim) % (self._n_param_shards * self._n_client_shards)
-                )
-                sig = fusion.state_signature(dim, n_hint)
-            elif bdim != dim:
-                raise ValueError(
-                    f"fuse_stream: block dim {bdim} != stream dim {dim}"
-                )
-            if rows > chunk:
-                raise ValueError(
-                    f"fuse_stream: block of {rows} rows exceeds "
-                    f"chunk_rows={chunk}"
-                )
-            rep.ingest_bytes += int(block.nbytes)   # pre-padding payload
-            if weighted:
-                wpad = np.zeros((pc,), np.float32)
-                wpad[:rows] = w
-                w_eff = np.array(
-                    fusion.effective_weights(jnp.asarray(wpad, jnp.float32))
-                )
-                if scale is not None:
-                    w_eff[:rows] *= np.asarray(scale, np.float32)[:rows]
-                w_eff[rows:] = 0.0         # effective_weights may remap pads
-            else:
-                # order-statistic fold: weights carry only row VALIDITY
-                w_eff = np.zeros((pc,), np.float32)
-                w_eff[:rows] = 1.0
-            t0 = time.perf_counter()
-            if compressed:
-                # host staging at the COMPRESSED size; the fp32 block
-                # exists only on device, between the dequant executable
-                # and the fold step
-                Pq, blk = block.codes.shape[1], block.block
-                if rows < pc:
-                    qpad = np.zeros((pc, Pq), np.int8)
-                    qpad[:rows] = block.codes
-                    spad = np.zeros((pc, Pq // blk), np.float32)
-                    spad[:rows] = block.scales
+            with spans.span("engine.stage"):
+                block, w = item[0], item[1]
+                scale = _check_scale(item[2]) if len(item) > 2 else None
+                if scale is not None and not weighted:
+                    raise ValueError(
+                        f"{fusion.name}: per-row staleness scales are "
+                        "unsupported — order statistics cannot discount rows"
+                    )
+                compressed = isinstance(block, CompressedBlock)
+                rows = block.rows if compressed else block.shape[0]
+                bdim = block.dim if compressed else block.shape[1]
+                if chunk is None:
+                    dim = bdim
+                    chunk = int(chunk_rows) if chunk_rows else rows
+                    rep.chunk_rows = chunk
+                    pc = chunk + (-chunk) % self._n_client_shards
+                    pdim = dim + (
+                        (-dim) % (self._n_param_shards * self._n_client_shards)
+                    )
+                    sig = fusion.state_signature(dim, n_hint)
+                elif bdim != dim:
+                    raise ValueError(
+                        f"fuse_stream: block dim {bdim} != stream dim {dim}"
+                    )
+                if rows > chunk:
+                    raise ValueError(
+                        f"fuse_stream: block of {rows} rows exceeds "
+                        f"chunk_rows={chunk}"
+                    )
+                rep.ingest_bytes += int(block.nbytes)   # pre-padding payload
+                if weighted:
+                    wpad = np.zeros((pc,), np.float32)
+                    wpad[:rows] = w
+                    w_eff = np.array(
+                        fusion.effective_weights(jnp.asarray(wpad, jnp.float32))
+                    )
+                    if scale is not None:
+                        w_eff[:rows] *= np.asarray(scale, np.float32)[:rows]
+                    w_eff[rows:] = 0.0         # effective_weights may remap pads
                 else:
-                    qpad, spad = block.codes, block.scales
-                cspec2 = P(self._cspec(), None) if weighted else P(None, None)
-                q_dev = _device_put(mesh, qpad, cspec2)
-                s_dev = _device_put(mesh, spad, cspec2)
-                deq = deqs.get((Pq, blk))
-                if deq is None:
-                    deq, c_s = self._dequant_fn(
-                        pc, Pq, blk, dim, pdim, in_u, weighted, q_dev,
-                        s_dev,
+                    # order-statistic fold: weights carry only row VALIDITY
+                    w_eff = np.zeros((pc,), np.float32)
+                    w_eff[:rows] = 1.0
+                t0 = time.perf_counter()
+                if compressed:
+                    # host staging at the COMPRESSED size; the fp32 block
+                    # exists only on device, between the dequant executable
+                    # and the fold step
+                    Pq, blk = block.codes.shape[1], block.block
+                    if rows < pc:
+                        qpad = np.zeros((pc, Pq), np.int8)
+                        qpad[:rows] = block.codes
+                        spad = np.zeros((pc, Pq // blk), np.float32)
+                        spad[:rows] = block.scales
+                    else:
+                        qpad, spad = block.codes, block.scales
+                    cspec2 = P(self._cspec(), None) if weighted else P(None, None)
+                    q_dev = _device_put(mesh, qpad, cspec2)
+                    s_dev = _device_put(mesh, spad, cspec2)
+                    deq = deqs.get((Pq, blk))
+                    if deq is None:
+                        deq, c_s = self._dequant_fn(
+                            pc, Pq, blk, dim, pdim, in_u, weighted, q_dev,
+                            s_dev,
+                        )
+                        deqs[(Pq, blk)] = deq
+                        compile_total += c_s
+                    u_dev = deq(q_dev, s_dev)
+                    dtype = np.dtype(np.float32)
+                else:
+                    if rows < pc or pdim != dim:  # shard-multiple/ragged pad
+                        padded = np.zeros((pc, pdim), block.dtype)
+                        padded[:rows, :dim] = block
+                        block = padded
+                    u_dev = _device_put(mesh, block, in_u)
+                    dtype = np.dtype(block.dtype)
+                w_dev = _device_put(mesh, w_eff, in_w)
+                rep.ingest_seconds += time.perf_counter() - t0
+                if state is None:
+                    host_state = self._stream_state_host(fusion, dim, pdim,
+                                                         n_hint, init)
+                    leaf_specs = tuple(
+                        self._leaf_spec(np.shape(x), pdim) for x in host_state
                     )
-                    deqs[(Pq, blk)] = deq
-                    compile_total += c_s
-                u_dev = deq(q_dev, s_dev)
-                dtype = np.dtype(np.float32)
-            else:
-                if rows < pc or pdim != dim:  # shard-multiple/ragged pad
-                    padded = np.zeros((pc, pdim), block.dtype)
-                    padded[:rows, :dim] = block
-                    block = padded
-                u_dev = _device_put(mesh, block, in_u)
-                dtype = np.dtype(block.dtype)
-            w_dev = _device_put(mesh, w_eff, in_w)
-            rep.ingest_seconds += time.perf_counter() - t0
-            if state is None:
-                host_state = self._stream_state_host(fusion, dim, pdim,
-                                                     n_hint, init)
-                leaf_specs = tuple(
-                    self._leaf_spec(np.shape(x), pdim) for x in host_state
-                )
-                state = tuple(
-                    _device_put(mesh, x, s)
-                    for x, s in zip(host_state, leaf_specs)
-                )
-            step = steps.get(dtype.str)
-            if step is None:
-                def build():
-                    def step_fn(u, wv, *leaves):
-                        st = tuple(leaves)
-                        if fusion.reducible:
-                            partial = lambda uu, ww: self._partials(
-                                fusion, uu, ww)
-                            new = fusion.fold_block(st, u, wv,
-                                                    partial=partial)
-                        else:
-                            # local carve per coordinate shard — rows are
-                            # replicated across client axes, no collective
-                            new = fusion.fold_block(st, u, wv)
-                        return tuple(new)
-
-                    return jax.shard_map(
-                        step_fn, mesh=mesh,
-                        in_specs=(in_u, in_w) + leaf_specs,
-                        out_specs=leaf_specs, check_vma=False,
+                    state = tuple(
+                        _device_put(mesh, x, s)
+                        for x, s in zip(host_state, leaf_specs)
                     )
+                step = steps.get(dtype.str)
+                if step is None:
+                    def build():
+                        def step_fn(u, wv, *leaves):
+                            st = tuple(leaves)
+                            if fusion.reducible:
+                                partial = lambda uu, ww: self._partials(
+                                    fusion, uu, ww)
+                                new = fusion.fold_block(st, u, wv,
+                                                        partial=partial)
+                            else:
+                                # local carve per coordinate shard — rows are
+                                # replicated across client axes, no collective
+                                new = fusion.fold_block(st, u, wv)
+                            return tuple(new)
 
-                step, compile_s = self.cache.get(
-                    self._stream_key(fusion, chunk, dim, dtype, sig),
-                    build, u_dev, w_dev, *state,
-                )
-                steps[dtype.str] = step
-                # mixed rounds accumulate one compile per payload kind
-                compile_total += compile_s
+                        return jax.shard_map(
+                            step_fn, mesh=mesh,
+                            in_specs=(in_u, in_w) + leaf_specs,
+                            out_specs=leaf_specs, check_vma=False,
+                        )
+
+                    step, compile_s = self.cache.get(
+                        self._stream_key(fusion, chunk, dim, dtype, sig),
+                        build, u_dev, w_dev, *state,
+                    )
+                    steps[dtype.str] = step
+                    # mixed rounds accumulate one compile per payload kind
+                    compile_total += compile_s
             rep.compile_seconds = compile_total
             self.last_compile_seconds = compile_total
             t0 = time.perf_counter()
-            with sem:
+            with sem, spans.span("engine.step"):
                 state = step(u_dev, w_dev, *state)
                 if device_sem is not None:
                     # async dispatch must not escape the execution bound
@@ -615,7 +615,8 @@ class DistributedEngine:
         # slice param-padded leaves back to the real dim BEFORE finalize:
         # padded coordinates carry garbage (inf sentinels on the carve
         # path) that must never reach the finalize arithmetic
-        host_leaves = tuple(np.asarray(x) for x in state)
+        with spans.span("engine.copyout"):
+            host_leaves = tuple(np.asarray(x) for x in state)
         sliced = tuple(
             x[..., :dim] if x.ndim and x.shape[-1] == pdim else x
             for x in host_leaves
@@ -624,7 +625,7 @@ class DistributedEngine:
         if fusion.reducible:
             rep.acc_wsum = sliced[0]
             rep.acc_tot = float(sliced[1])
-        with sem:
+        with sem, spans.span("engine.finalize"):
             fused = jax.block_until_ready(fusion.finalize(sliced))  # lint: disable=sync-under-sem -- deliberate: the permit must cover device EXECUTION, not just dispatch (PR 5's device_concurrency contract)
         rep.compute_seconds += time.perf_counter() - t0
         return fused, rep

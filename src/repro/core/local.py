@@ -26,7 +26,6 @@ FedAdam carry python-side server state that must advance every round.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import threading
 import time
@@ -43,6 +42,7 @@ from repro.kernels.fused_fusion.kernel import (
     weighted_sum_pallas,
 )
 from repro.kernels.robust_fusion.kernel import topk_carve_pallas
+from repro.utils import spans
 from repro.utils.jitcache import CompiledCache, bucket_rows, fusion_cache_key
 
 # fusions whose weighted-sum partial routes through the fused Pallas kernel
@@ -71,7 +71,9 @@ class StreamReport:
 
     ingest_seconds: float = 0.0    # stalls waiting on store blocks
     compile_seconds: float = 0.0   # executable build (0.0 on warm rounds)
-    compute_seconds: float = 0.0   # device time in the step executable
+    # the step calls and finalize, each with its wait for the device
+    # semaphore, plus the state copy-out: not device time alone
+    compute_seconds: float = 0.0
     n_rows: int = 0
     n_blocks: int = 0
     chunk_rows: int = 0
@@ -137,8 +139,7 @@ class LocalEngine:
         n, P = updates.shape
         batch_bytes = updates.dtype.itemsize * P
         self.last_compile_seconds = 0.0
-        sem = device_sem if device_sem is not None \
-            else contextlib.nullcontext()
+        sem = spans.DeviceSlot(device_sem)
 
         if self.memory_cap_bytes is not None:
             max_rows = max(int(self.memory_cap_bytes // max(batch_bytes, 1)), 1)
@@ -249,8 +250,7 @@ class LocalEngine:
             )
         weighted = fusion.weighted
         rep = StreamReport()
-        sem = device_sem if device_sem is not None \
-            else contextlib.nullcontext()
+        sem = spans.DeviceSlot(device_sem)
         it = iter(blocks)
         steps: dict = {}   # payload kind -> cached step executable
         state = sig = None  # flat tuple of jnp leaves + its cache sig
@@ -264,86 +264,87 @@ class LocalEngine:
             except StopIteration:
                 break
             rep.ingest_seconds += time.perf_counter() - t0
-            block, w = item[0], item[1]
-            scale = _check_scale(item[2]) if len(item) > 2 else None
-            if scale is not None and not weighted:
-                raise ValueError(
-                    f"{fusion.name}: per-row staleness scales are "
-                    "unsupported — order statistics cannot discount rows"
-                )
-            compressed = isinstance(block, CompressedBlock)
-            rows = block.rows if compressed else block.shape[0]
-            bdim = block.dim if compressed else block.shape[1]
-            if chunk is None:
-                dim = bdim
-                chunk = int(chunk_rows) if chunk_rows else rows
-                rep.chunk_rows = chunk
-                state = self._stream_state(fusion, dim, n_hint, init)
-                sig = fusion.state_signature(dim, n_hint)
-            elif bdim != dim:
-                raise ValueError(
-                    f"fuse_stream: block dim {bdim} != stream dim {dim}"
-                )
-            rep.ingest_bytes += int(block.nbytes)   # pre-padding payload
-            kind = ("q", block.codes.shape[1], block.block) if compressed \
-                else ("d", np.dtype(block.dtype).str)
-            step = steps.get(kind)
-            if step is None:
-                avals = tuple(
-                    jax.ShapeDtypeStruct(np.shape(leaf),
-                                         np.asarray(leaf).dtype)
-                    for leaf in state
-                )
-                if compressed:
-                    step, compile_s = self._stream_step_q(
-                        fusion, chunk, dim, block.codes.shape[1],
-                        block.block, sig, avals,
+            with spans.span("engine.stage"):
+                block, w = item[0], item[1]
+                scale = _check_scale(item[2]) if len(item) > 2 else None
+                if scale is not None and not weighted:
+                    raise ValueError(
+                        f"{fusion.name}: per-row staleness scales are "
+                        "unsupported — order statistics cannot discount rows"
                     )
+                compressed = isinstance(block, CompressedBlock)
+                rows = block.rows if compressed else block.shape[0]
+                bdim = block.dim if compressed else block.shape[1]
+                if chunk is None:
+                    dim = bdim
+                    chunk = int(chunk_rows) if chunk_rows else rows
+                    rep.chunk_rows = chunk
+                    state = self._stream_state(fusion, dim, n_hint, init)
+                    sig = fusion.state_signature(dim, n_hint)
+                elif bdim != dim:
+                    raise ValueError(
+                        f"fuse_stream: block dim {bdim} != stream dim {dim}"
+                    )
+                rep.ingest_bytes += int(block.nbytes)   # pre-padding payload
+                kind = ("q", block.codes.shape[1], block.block) if compressed \
+                    else ("d", np.dtype(block.dtype).str)
+                step = steps.get(kind)
+                if step is None:
+                    avals = tuple(
+                        jax.ShapeDtypeStruct(np.shape(leaf),
+                                             np.asarray(leaf).dtype)
+                        for leaf in state
+                    )
+                    if compressed:
+                        step, compile_s = self._stream_step_q(
+                            fusion, chunk, dim, block.codes.shape[1],
+                            block.block, sig, avals,
+                        )
+                    else:
+                        step, compile_s = self._stream_step(
+                            fusion, chunk, dim, block.dtype, sig, avals,
+                        )
+                    steps[kind] = step
+                    # mixed rounds accumulate one compile per payload kind
+                    compile_total += compile_s
+                    rep.compile_seconds = compile_total
+                    self.last_compile_seconds = compile_total
+                if rows > chunk:
+                    raise ValueError(
+                        f"fuse_stream: block of {rows} rows exceeds "
+                        f"chunk_rows={chunk}"
+                    )
+                if rows < chunk:           # ragged final block: zero-weight pad
+                    wpad = np.zeros((chunk,), np.float32)
+                    wpad[:rows] = w
+                    w = wpad
+                    if compressed:
+                        qpad = np.zeros((chunk, block.codes.shape[1]), np.int8)
+                        qpad[:rows] = block.codes
+                        spad = np.zeros(
+                            (chunk, block.scales.shape[1]), np.float32
+                        )
+                        spad[:rows] = block.scales
+                        block = CompressedBlock(codes=qpad, scales=spad,
+                                                dim=dim)
+                    else:
+                        padded = np.zeros((chunk, dim), block.dtype)
+                        padded[:rows] = block
+                        block = padded
+                if weighted:
+                    w = np.array(
+                        fusion.effective_weights(jnp.asarray(w, jnp.float32))
+                    )
+                    if scale is not None:
+                        w[:rows] *= np.asarray(scale, np.float32)[:rows]
+                    if rows < chunk:
+                        w[rows:] = 0.0     # effective_weights may remap pads
                 else:
-                    step, compile_s = self._stream_step(
-                        fusion, chunk, dim, block.dtype, sig, avals,
-                    )
-                steps[kind] = step
-                # mixed rounds accumulate one compile per payload kind
-                compile_total += compile_s
-                rep.compile_seconds = compile_total
-                self.last_compile_seconds = compile_total
-            if rows > chunk:
-                raise ValueError(
-                    f"fuse_stream: block of {rows} rows exceeds "
-                    f"chunk_rows={chunk}"
-                )
-            if rows < chunk:           # ragged final block: zero-weight pad
-                wpad = np.zeros((chunk,), np.float32)
-                wpad[:rows] = w
-                w = wpad
-                if compressed:
-                    qpad = np.zeros((chunk, block.codes.shape[1]), np.int8)
-                    qpad[:rows] = block.codes
-                    spad = np.zeros(
-                        (chunk, block.scales.shape[1]), np.float32
-                    )
-                    spad[:rows] = block.scales
-                    block = CompressedBlock(codes=qpad, scales=spad,
-                                            dim=dim)
-                else:
-                    padded = np.zeros((chunk, dim), block.dtype)
-                    padded[:rows] = block
-                    block = padded
-            if weighted:
-                w = np.array(
-                    fusion.effective_weights(jnp.asarray(w, jnp.float32))
-                )
-                if scale is not None:
-                    w[:rows] *= np.asarray(scale, np.float32)[:rows]
-                if rows < chunk:
-                    w[rows:] = 0.0     # effective_weights may remap pads
-            else:
-                # order-statistic fold: weights carry only row VALIDITY
-                w = np.zeros((chunk,), np.float32)
-                w[:rows] = 1.0
+                    # order-statistic fold: weights carry only row VALIDITY
+                    w = np.zeros((chunk,), np.float32)
+                    w[:rows] = 1.0
             t0 = time.perf_counter()
-            with sem:
+            with sem, spans.span("engine.step"):
                 if compressed:
                     state = step(block.codes, block.scales, w, *state)
                 else:
@@ -362,11 +363,12 @@ class LocalEngine:
             # carry-only round: nothing arrived, finalize the carried state
             state = tuple(jnp.asarray(x, jnp.float32) for x in init)
         t0 = time.perf_counter()
-        rep.acc_state = tuple(np.asarray(leaf) for leaf in state)
+        with spans.span("engine.copyout"):
+            rep.acc_state = tuple(np.asarray(leaf) for leaf in state)
         if fusion.reducible:
             rep.acc_wsum = rep.acc_state[0]
             rep.acc_tot = float(rep.acc_state[1])
-        with sem:
+        with sem, spans.span("engine.finalize"):
             fused = jax.block_until_ready(fusion.finalize(state))  # lint: disable=sync-under-sem -- deliberate: the permit must cover device EXECUTION, not just dispatch (PR 5's device_concurrency contract)
         rep.compute_seconds += time.perf_counter() - t0
         return fused, rep
@@ -480,8 +482,7 @@ class LocalEngine:
         if B != n:   # zero-weight rows: no contribution to any reducible op
             updates = jnp.pad(updates, ((0, B - n), (0, 0)))
             weights = jnp.pad(weights, (0, B - n))
-        sem = device_sem if device_sem is not None \
-            else contextlib.nullcontext()
+        sem = spans.DeviceSlot(device_sem)
         with sem:
             wsum, tot = fn(updates, weights)
             return self._bounded(fusion.combine(wsum, tot), device_sem)
@@ -646,8 +647,7 @@ class LocalEngine:
         if padded_n != n:
             updates = jnp.pad(updates, ((0, padded_n - n), (0, 0)))
             weights = jnp.pad(weights, (0, padded_n - n))
-        sem = device_sem if device_sem is not None \
-            else contextlib.nullcontext()
+        sem = spans.DeviceSlot(device_sem)
         with sem:
             wsum, tot = fn(
                 updates.reshape(k, max_rows, P),

@@ -19,6 +19,7 @@ import time
 from typing import Callable, Optional
 
 from repro.core.store import UpdateStore
+from repro.utils import spans
 
 
 @dataclasses.dataclass
@@ -88,11 +89,13 @@ class Monitor:
 
     def wait(self) -> MonitorResult:
         start = self.clock()
-        while True:
-            count = self.store.count(self.tenant)
-            waited = self.clock() - start
-            if self.should_close(count, waited):
-                return self.result(count, waited)
-            # event-driven under the real clock (woken by the store's
-            # arrival condition); injected sleeps drive scripted time
-            self.store.wait_for_arrival(self.poll_interval, self.sleep)
+        with spans.span("monitor.wait"):
+            while True:
+                count = self.store.count(self.tenant)
+                waited = self.clock() - start
+                if self.should_close(count, waited):
+                    return self.result(count, waited)
+                # event-driven under the real clock (woken by the store's
+                # arrival condition); injected sleeps drive scripted time
+                self.store.wait_for_arrival(self.poll_interval,
+                                            self.sleep)

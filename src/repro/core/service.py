@@ -85,6 +85,7 @@ engines, which is the system's core invariant.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -109,6 +110,7 @@ from repro.core.monitor import Monitor, MonitorResult
 from repro.core.planner import Plan, Planner
 from repro.core.store import DEFAULT_TENANT, StoreStats, UpdateStore
 from repro.core.workload import Workload, WorkloadClass, classify
+from repro.utils import spans
 from repro.utils.mem import HardwareSpec, detect_hardware
 from repro.utils.pytree import flat_vector_to_tree, tree_to_flat_vector
 
@@ -133,7 +135,11 @@ class RoundReport:
     route_next_to_store: bool = False
     streamed: bool = False       # True: chunked store pipeline (no dense n,P)
     # ingest (store -> host blocks) / compile (executable build; 0.0 on
-    # warm rounds) / compute (device time) — the paper's Fig. 12 phases
+    # warm rounds) / compute (the engine's fold: per-block host staging,
+    # the wait for ``device_sem`` behind other rounds' folds, transfer,
+    # fold steps, state copy-out and finalize — not device time alone)
+    # — the paper's Fig. 12 phases; ``FairRoundScheduler`` adds queue
+    # (submit to admission: the wait for a running slot)
     phase_seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
     # seconds of the monitor window during which fusion work proceeded
     # CONCURRENTLY with the straggler wait (0.0 on serialized rounds)
@@ -154,6 +160,9 @@ class RoundReport:
     # operator-facing routing notes, e.g. why a robust round fell back
     # from the streamed carve to the dense path (state budget exceeded)
     notes: Tuple[str, ...] = ()
+    # the service's round sequence number: the ``round`` stat of the
+    # round's ``repro.*`` spans
+    round_id: int = 0
 
 
 class AggregationService:
@@ -352,6 +361,7 @@ class AggregationService:
             ) if adaptive else None
         )
         self.history: List[RoundReport] = []  # guarded-by: _state_lock
+        self._round_seq = itertools.count(1)   # RoundReport.round_id
 
     # -- quantized transport --------------------------------------------------
     def compress_update(
@@ -526,11 +536,15 @@ class AggregationService:
         ``(None, report)`` with ``report.empty`` set instead of
         raising. ``template`` (a model pytree) unflattens the fused
         vector back into model structure."""
-        with self._round_lock(tenant):
-            return self._aggregate_impl(
-                updates, weights, template, expected_clients,
-                from_store, async_round, tenant, val_grad,
-            )
+        rid = next(self._round_seq)
+        with spans.scope(tenant=tenant, round=rid), spans.span("round"):
+            with self._round_lock(tenant):
+                fused, report = self._aggregate_impl(
+                    updates, weights, template, expected_clients,
+                    from_store, async_round, tenant, val_grad,
+                )
+        report.round_id = rid
+        return fused, report
 
     def _aggregate_impl(
         self,
@@ -811,13 +825,14 @@ class AggregationService:
         if t_round_store is None:
             t_round_store = self.store.clock()
         # learn (P, dtype) from the first arrival — or time out empty
-        while True:
-            count = self.store.count(tenant)
-            waited = monitor.clock() - t_round
-            if count > 0 or monitor.should_close(count, waited):
-                break
-            self.store.wait_for_arrival(monitor.poll_interval,
-                                        monitor.sleep)
+        with spans.span("monitor.wait"):
+            while True:
+                count = self.store.count(tenant)
+                waited = monitor.clock() - t_round
+                if count > 0 or monitor.should_close(count, waited):
+                    break
+                self.store.wait_for_arrival(monitor.poll_interval,
+                                            monitor.sleep)
         if self.store.count(tenant) == 0:
             mr = monitor.result(0, monitor.clock() - t_round)
             return self._empty_round(
@@ -900,7 +915,9 @@ class AggregationService:
         # partition (version-checked — an update re-written mid-round
         # survives for the next round); what raced past the close stays,
         # one round staler
-        self.store.remove(folded, versions=folded_versions, tenant=tenant)
+        with spans.span("store.consume", n=len(folded)):
+            self.store.remove(folded, versions=folded_versions,
+                              tenant=tenant)
         # compute the next-age map BEFORE taking the state lock:
         # client_ids() takes the STORE lock, and the declared order
         # (state inner-most) forbids acquiring it under _state_lock
@@ -1243,7 +1260,8 @@ class FairRoundScheduler:
         self._vtime: Dict[str, float] = {}
         self._closed = False
         self._drained = False
-        self._admitted = 0
+        self._admitted = 0  # guarded-by: _lock
+        self._slot_wait_s = 0.0  # guarded-by: _lock
         self._admission_order: List[str] = []
         self._workers: List[threading.Thread] = []
         self._loop = threading.Thread(
@@ -1265,7 +1283,7 @@ class FairRoundScheduler:
             q = self._waiting.get(tenant)
             if q is None:
                 q = self._waiting[tenant] = queue.SimpleQueue()
-            q.put((fut, aggregate_kwargs))
+            q.put((fut, aggregate_kwargs, time.monotonic()))
             self._waiting_count[tenant] = (
                 self._waiting_count.get(tenant, 0) + 1
             )
@@ -1331,7 +1349,9 @@ class FairRoundScheduler:
                         return
                     self._wake.wait(timeout=0.5)
                     tenant = self._eligible_locked()
-                fut, kwargs = self._waiting[tenant].get_nowait()
+                fut, kwargs, submitted = self._waiting[tenant].get_nowait()
+                slot_wait = time.monotonic() - submitted
+                self._slot_wait_s += slot_wait
                 self._waiting_count[tenant] -= 1
                 fp = self._footprint(tenant)
                 self._running[tenant] = fp
@@ -1349,7 +1369,7 @@ class FairRoundScheduler:
                 self._admitted += 1
                 self._admission_order.append(tenant)
             worker = threading.Thread(
-                target=self._run_one, args=(tenant, fut, kwargs),
+                target=self._run_one, args=(tenant, fut, kwargs, slot_wait),
                 name=f"fair-round:{tenant}", daemon=True,
             )
             # track round workers so shutdown() can join them — a
@@ -1362,16 +1382,17 @@ class FairRoundScheduler:
                 self._workers.append(worker)
             worker.start()
 
-    def _run_one(self, tenant: str, fut: "Future", kwargs: dict) -> None:
+    def _run_one(self, tenant: str, fut: "Future", kwargs: dict,
+                 slot_wait: float) -> None:
         if not fut.set_running_or_notify_cancel():
             with self._wake:
                 self._running.pop(tenant, None)
                 self._wake.notify_all()
             return
         try:
-            fut.set_result(
-                self.service.aggregate(tenant=tenant, **kwargs)
-            )
+            fused, report = self.service.aggregate(tenant=tenant, **kwargs)
+            report.phase_seconds["queue"] = slot_wait
+            fut.set_result((fused, report))
         except BaseException as exc:
             fut.set_exception(exc)
         finally:
@@ -1394,6 +1415,14 @@ class FairRoundScheduler:
         """Tenants in admission order (the fairness audit trail)."""
         with self._lock:
             return list(self._admission_order)
+
+    def stats(self) -> dict:
+        """Rounds admitted and running, and ``slot_wait_s``: the summed
+        wait of the admitted rounds from submit to admission."""
+        with self._lock:
+            return {"admitted": self._admitted,
+                    "running": len(self._running),
+                    "slot_wait_s": self._slot_wait_s}
 
     def shutdown(self, wait: bool = True) -> None:
         """Stop accepting submissions; drain waiting rounds, then stop

@@ -94,6 +94,7 @@ from typing import (
 import numpy as np
 
 from repro.core.compress import CompressedBlock, CompressedUpdate
+from repro.utils import spans
 from repro.utils.pytree import tree_to_flat_vector
 
 # the partition untagged writes land in; also the root of a disk spool
@@ -995,60 +996,62 @@ class UpdateStore:
         consumption (``remove``); it is keyed by client id, so it is
         only meaningful for single-tenant batches. ``keys_out``
         collects the keys actually loaded."""
-        groups: Dict[tuple, Tuple[list, list, List[_Key]]] = {}
-        n_loaded = 0
-        for key in batch:
-            try:
-                u, w, v = self._read_versioned(key)
-            except (KeyError, FileNotFoundError):
-                continue   # consumed/evicted mid-flight: skip the row
-            if versions_out is not None:
-                versions_out[key[1]] = v
-            if keys_out is not None:
-                keys_out.append(key)
-            if isinstance(u, CompressedUpdate):
-                kind = ("q", u.codes.shape[0], u.scales.shape[0], u.dim)
-            else:
-                kind = ("d", u.dtype.str, u.shape[0])
-            ups, ws, loaded = groups.setdefault(kind, ([], [], []))
-            ups.append(u)
-            ws.append(w)
-            loaded.append(key)
-            n_loaded += 1
-        if not n_loaded:
-            return None
-        out: List[Tuple[object, np.ndarray, List[_Key]]] = []
-        total_bytes = 0
-        per_tenant: Dict[str, Tuple[int, int]] = {}
-        for kind, (ups, ws, loaded) in groups.items():
-            if kind[0] == "q":
-                payload: object = CompressedBlock(
-                    codes=np.stack([cu.codes for cu in ups]),
-                    scales=np.stack([cu.scales for cu in ups]),
-                    dim=kind[3],
+        tenant = batch[0][0] if batch else DEFAULT_TENANT
+        with spans.span("store.load", tenant=tenant, n=len(batch)):
+            groups: Dict[tuple, Tuple[list, list, List[_Key]]] = {}
+            n_loaded = 0
+            for key in batch:
+                try:
+                    u, w, v = self._read_versioned(key)
+                except (KeyError, FileNotFoundError):
+                    continue   # consumed/evicted mid-flight: skip the row
+                if versions_out is not None:
+                    versions_out[key[1]] = v
+                if keys_out is not None:
+                    keys_out.append(key)
+                if isinstance(u, CompressedUpdate):
+                    kind = ("q", u.codes.shape[0], u.scales.shape[0], u.dim)
+                else:
+                    kind = ("d", u.dtype.str, u.shape[0])
+                ups, ws, loaded = groups.setdefault(kind, ([], [], []))
+                ups.append(u)
+                ws.append(w)
+                loaded.append(key)
+                n_loaded += 1
+            if not n_loaded:
+                return None
+            out: List[Tuple[object, np.ndarray, List[_Key]]] = []
+            total_bytes = 0
+            per_tenant: Dict[str, Tuple[int, int]] = {}
+            for kind, (ups, ws, loaded) in groups.items():
+                if kind[0] == "q":
+                    payload: object = CompressedBlock(
+                        codes=np.stack([cu.codes for cu in ups]),
+                        scales=np.stack([cu.scales for cu in ups]),
+                        dim=kind[3],
+                    )
+                    nbytes = payload.nbytes
+                else:
+                    payload = np.stack(ups)
+                    nbytes = payload.nbytes
+                out.append((payload, np.asarray(ws, np.float32), loaded))
+                total_bytes += nbytes
+                row_bytes = nbytes // max(len(ups), 1)
+                for t, _ in loaded:
+                    n_r, b_r = per_tenant.get(t, (0, 0))
+                    per_tenant[t] = (n_r + 1, b_r + row_bytes)
+            with self._lock:
+                self.stats.reads += n_loaded
+                self.stats.bytes_read += total_bytes
+                self.stats.peak_block_bytes = max(
+                    self.stats.peak_block_bytes, total_bytes
                 )
-                nbytes = payload.nbytes
-            else:
-                payload = np.stack(ups)
-                nbytes = payload.nbytes
-            out.append((payload, np.asarray(ws, np.float32), loaded))
-            total_bytes += nbytes
-            row_bytes = nbytes // max(len(ups), 1)
-            for t, _ in loaded:
-                n_r, b_r = per_tenant.get(t, (0, 0))
-                per_tenant[t] = (n_r + 1, b_r + row_bytes)
-        with self._lock:
-            self.stats.reads += n_loaded
-            self.stats.bytes_read += total_bytes
-            self.stats.peak_block_bytes = max(
-                self.stats.peak_block_bytes, total_bytes
-            )
-            for t, (n_r, b_r) in per_tenant.items():
-                ts = self._tstats(t)
-                ts.reads += n_r
-                ts.bytes_read += b_r
-                ts.peak_block_bytes = max(ts.peak_block_bytes, b_r)
-        return out
+                for t, (n_r, b_r) in per_tenant.items():
+                    ts = self._tstats(t)
+                    ts.reads += n_r
+                    ts.bytes_read += b_r
+                    ts.peak_block_bytes = max(ts.peak_block_bytes, b_r)
+            return out
 
     def iter_arrivals(
         self,
@@ -1122,7 +1125,8 @@ class UpdateStore:
                 return
             # event-driven under the real clock: wake on the next write's
             # condition notify instead of burning the full poll interval
-            self.wait_for_arrival(poll_interval, sleep)
+            with spans.span("monitor.wait"):
+                self.wait_for_arrival(poll_interval, sleep)
 
     def read_stacked(
         self, tenant: Optional[str] = None

@@ -183,9 +183,14 @@ class EdgeAggregatorServer:
         return {t: f.result() for t, f in futs.items()}
 
     def metrics(self) -> dict:
+        """The front-end's counters (``/v1/healthz``), the rounds
+        admitted and running, and ``slot_wait_s``: the summed wait of
+        admitted rounds for a running slot."""
         out = self.frontend.metrics()
-        out["rounds_admitted"] = len(self.scheduler.admission_order())
-        out["rounds_running"] = len(self.scheduler.running())
+        sched = self.scheduler.stats()
+        out["rounds_admitted"] = sched["admitted"]
+        out["rounds_running"] = sched["running"]
+        out["slot_wait_s"] = sched["slot_wait_s"]
         return out
 
     def close(self) -> None:

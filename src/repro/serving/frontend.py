@@ -28,6 +28,7 @@ import json
 import socket
 import sys
 import threading
+import time
 from concurrent.futures import TimeoutError as FutureTimeout
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Optional, Tuple
@@ -37,6 +38,7 @@ from repro.core.store import QuotaExceededError
 from repro.serving.admission import AdmissionController
 from repro.serving.ingest import BackpressureError, IngestQueue
 from repro.serving.protocol import WireError, parse_update
+from repro.utils import spans
 
 
 class _Httpd(ThreadingHTTPServer):
@@ -142,8 +144,10 @@ class _Handler(BaseHTTPRequestHandler):
             self._send(decision.status, {"error": decision.reason},
                        retry_after=decision.retry_after, close=True)
             return
+        t0 = time.monotonic()
         try:
-            body = self._read_exact(length)
+            with spans.span("frontend.read", tenant=tenant):
+                body = self._read_exact(length)
         except (socket.timeout, TimeoutError):
             ing.count("read_timeout")
             self._send(408, {"error": f"body read exceeded "
@@ -160,12 +164,16 @@ class _Handler(BaseHTTPRequestHandler):
             ing.count("disconnect")
             self.close_connection = True
             return
+        t1 = time.monotonic()
         try:
-            parsed = parse_update(body)
+            with spans.span("frontend.parse", tenant=tenant) as sp:
+                parsed = parse_update(body)
+                sp.set_metadata(client=parsed.client_id)
         except WireError as e:
             ing.count("malformed")
             self._send(400, {"error": str(e)})
             return
+        t2 = time.monotonic()
         try:
             fut = ing.queue.submit(parsed.client_id, parsed.update,
                                    weight=parsed.weight, tenant=tenant)
@@ -175,7 +183,9 @@ class _Handler(BaseHTTPRequestHandler):
                        retry_after=e.retry_after, close=True)
             return
         try:
-            latency = fut.result(timeout=ing.commit_timeout)
+            with spans.span("frontend.ack_wait", tenant=tenant,
+                            client=parsed.client_id):
+                latency = fut.result(timeout=ing.commit_timeout)
         except QuotaExceededError as e:
             ing.count("quota_reject")
             self._send(429, {"error": str(e)},
@@ -189,7 +199,7 @@ class _Handler(BaseHTTPRequestHandler):
             ing.count("malformed")
             self._send(400, {"error": str(e)})
             return
-        ing.count("accepted")
+        ing.accepted(read_s=t1 - t0, parse_s=t2 - t1)
         self._send(200, {
             "status": "ok", "tenant": tenant,
             "client_id": parsed.client_id,
@@ -258,8 +268,10 @@ class IngestServer:
         self.queue = ingest_queue or IngestQueue(
             store, maxsize=queue_size, batch_max=batch_max
         )
-        self._counters: Dict[str, int] = {}  # guarded-by: _clock_lock
-        self._clock_lock = threading.Lock()
+        # upload outcomes by name, and read_s / parse_s: the seconds
+        # spent reading and parsing the accepted uploads' bodies
+        self._counters: Dict[str, float] = {"read_s": 0.0, "parse_s": 0.0}  # guarded-by: _counters_lock
+        self._counters_lock = threading.Lock()
         self._httpd = _Httpd((host, port), _Handler)
         self._httpd.ingest = self
         self._thread = threading.Thread(
@@ -272,11 +284,19 @@ class IngestServer:
 
     # -- accounting ----------------------------------------------------------
     def count(self, name: str) -> None:
-        with self._clock_lock:
+        with self._counters_lock:
             self._counters[name] = self._counters.get(name, 0) + 1
 
+    def accepted(self, read_s: float, parse_s: float) -> None:
+        """Count one accepted upload and the seconds its body took to
+        read and to parse."""
+        with self._counters_lock:
+            self._counters["accepted"] = self._counters.get("accepted", 0) + 1
+            self._counters["read_s"] += read_s
+            self._counters["parse_s"] += parse_s
+
     def metrics(self) -> dict:
-        with self._clock_lock:
+        with self._counters_lock:
             out = dict(self._counters)
         out.update(self.queue.stats())
         return out

@@ -19,10 +19,12 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from concurrent.futures import Future
 from typing import List, Optional, Tuple
 
 from repro.core.store import DEFAULT_TENANT
+from repro.utils import spans
 
 _SENTINEL = object()
 
@@ -58,6 +60,8 @@ class IngestQueue:
         self._shed = 0  # guarded-by: _lock
         self._batches = 0  # guarded-by: _lock
         self._max_batch = 0  # guarded-by: _lock
+        self._queue_wait_s = 0.0  # guarded-by: _lock
+        self._commit_wait_s = 0.0  # guarded-by: _lock
         self._thread = threading.Thread(
             target=self._run, name="ingest-committer", daemon=True
         )
@@ -75,7 +79,8 @@ class IngestQueue:
             self._submitted += 1
         fut: Future = Future()
         try:
-            self._q.put_nowait((fut, (client_id, update, weight, tenant)))
+            self._q.put_nowait((fut, (client_id, update, weight, tenant),
+                                time.monotonic()))
         except queue.Full:
             with self._lock:
                 self._shed += 1
@@ -109,10 +114,15 @@ class IngestQueue:
         while True:
             batch, stop = self._drain()
             if batch:
-                futs = [f for f, _ in batch]
-                items = [it for _, it in batch]
+                t0 = time.monotonic()
+                waited = sum(t0 - enq for _, _, enq in batch)
+                futs = [f for f, _, _ in batch]
+                items = [it for _, it, _ in batch]
                 try:
-                    results = self.store.write_batch(items)
+                    with spans.span("ingest.commit", n=len(items),
+                                    queue_wait_s=waited):
+                        results = self.store.write_batch(items)
+                    commit_s = time.monotonic() - t0
                 except BaseException as exc:   # store hard-failed
                     for f in futs:
                         f.set_exception(exc)
@@ -130,6 +140,8 @@ class IngestQueue:
                                               len(batch))
                         self._committed += ok
                         self._rejected += len(batch) - ok
+                        self._queue_wait_s += waited
+                        self._commit_wait_s += ok * commit_s
             if stop:
                 return
 
@@ -139,6 +151,10 @@ class IngestQueue:
         return self._q.qsize()
 
     def stats(self) -> dict:
+        """Counters. ``queue_wait_s`` sums, over the uploads the store
+        took or refused (committed + rejected), the time from enqueue to
+        the committer's drain; ``commit_wait_s`` sums, over committed
+        uploads, their batch's ``write_batch`` time."""
         with self._lock:
             return {
                 "submitted": self._submitted,
@@ -147,6 +163,8 @@ class IngestQueue:
                 "shed": self._shed,
                 "batches": self._batches,
                 "max_batch": self._max_batch,
+                "queue_wait_s": self._queue_wait_s,
+                "commit_wait_s": self._commit_wait_s,
             }
 
     def close(self, timeout: Optional[float] = 10.0) -> None:

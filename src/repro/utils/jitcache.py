@@ -32,6 +32,8 @@ from typing import Callable, Dict, Hashable, Tuple
 
 import jax
 
+from repro.utils import spans
+
 # the checkout root (this file is src/repro/utils/jitcache.py)
 _CHECKOUT = Path(__file__).resolve().parents[3]
 
@@ -172,15 +174,16 @@ class CompiledCache:
         # shapes' lookups must not serialize behind it. This thread owns
         # the key's in-flight slot; same-key racers wait in _claim.
         try:
-            fn = builder()
+            with spans.span("engine.compile", key=str(key)):
+                fn = builder()
 
-            def traced(*args):
-                note_trace()
-                return fn(*args)
+                def traced(*args):
+                    note_trace()
+                    return fn(*args)
 
-            t0 = time.perf_counter()
-            compiled = jax.jit(traced).lower(*arg_specs).compile()
-            dt = time.perf_counter() - t0
+                t0 = time.perf_counter()
+                compiled = jax.jit(traced).lower(*arg_specs).compile()
+                dt = time.perf_counter() - t0
             with self._lock:
                 self._entries[key] = CacheEntry(
                     fn=compiled, compile_seconds=dt
@@ -225,13 +228,14 @@ class CompiledCache:
         if done is not None:
             return done[0]
         try:
-            fn = builder()
+            with spans.span("engine.compile", key=str(key)):
+                fn = builder()
 
-            def traced(*args):
-                note_trace()
-                return fn(*args)
+                def traced(*args):
+                    note_trace()
+                    return fn(*args)
 
-            jitted = jax.jit(traced)
+                jitted = jax.jit(traced)
             with self._lock:
                 self._entries[key] = CacheEntry(
                     fn=jitted, compile_seconds=0.0

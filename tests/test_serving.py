@@ -16,6 +16,7 @@ import subprocess
 import sys
 import threading
 import time
+import types
 import urllib.request
 
 import numpy as np
@@ -388,7 +389,8 @@ class _FakeService:
         time.sleep(0.01)
         with self.lock:
             self.active -= 1
-        return (np.zeros(2), None)
+        # the scheduler adds its slot wait to the report's phases
+        return (np.zeros(2), types.SimpleNamespace(phase_seconds={}))
 
     def _row_bytes(self, p, dtype):
         return p * 4
@@ -474,6 +476,72 @@ def test_fair_scheduler_capacity_gate():
     assert sorted(svc.calls) == ["a", "b"]
 
 
+def test_upload_counters_rise_per_accepted_upload():
+    """read_s / parse_s rise with each accepted upload only; the
+    queue's queue_wait_s and commit_wait_s with each committed one."""
+    store = UpdateStore()
+    with IngestServer(store, TOKENS) as srv:
+        m0 = srv.metrics()
+        assert m0["read_s"] == m0["parse_s"] == 0.0
+        assert m0["queue_wait_s"] == m0["commit_wait_s"] == 0.0
+        cli = HttpStoreClient("127.0.0.1", srv.port, token="tok-a")
+        for i, vec in enumerate(_payloads(3, 4096)):
+            cli.write(f"c{i}", vec, tenant="appa")
+        m3 = srv.metrics()
+        status, _, _ = _post_raw(srv.port, b"FLU1 not a frame")
+        assert status == 400
+        m4 = srv.metrics()
+    assert m3["accepted"] == 3 and m3["committed"] == 3
+    assert m3["read_s"] > 0 and m3["parse_s"] > 0
+    assert m3["queue_wait_s"] > 0 and m3["commit_wait_s"] > 0
+    # a malformed frame is read and parsed, but not accepted: the sums
+    # stay those of the accepted uploads
+    assert m4["malformed"] == 1 and m4["accepted"] == 3
+    for k in ("read_s", "parse_s", "queue_wait_s", "commit_wait_s"):
+        assert m4[k] == m3[k], k
+
+
+def test_ingest_queue_counts_the_wait_before_the_drain():
+    """An upload queued behind a slow commit waits about that long."""
+
+    class _SlowStore:
+        def write_batch(self, items):
+            time.sleep(0.2)
+            return [0.0] * len(items)
+
+    q = IngestQueue(_SlowStore(), batch_max=1)
+    try:
+        futs = [q.submit(f"c{i}", None) for i in range(2)]
+        for f in futs:
+            f.result(timeout=10)
+        st = q.stats()
+    finally:
+        q.close()
+    assert st["committed"] == 2 and st["batches"] == 2
+    assert 0.4 <= st["commit_wait_s"] < 2.0   # two commits of 0.2 s
+    assert 0.15 <= st["queue_wait_s"] < 2.0   # the second waited one
+
+
+def test_fair_scheduler_counts_slot_wait():
+    """slot_wait_s sums submit-to-admission over admitted rounds, and
+    each report's phase_seconds["queue"] holds its own wait."""
+    svc = _FakeService()
+    svc.block.clear()
+    with FairRoundScheduler(svc, max_running=1) as sched:
+        f1 = sched.submit("a")
+        f2 = sched.submit("b")   # waits for a's slot
+        time.sleep(0.3)
+        assert sched.stats()["running"] == 1
+        svc.block.set()
+        (_, r1), (_, r2) = f1.result(timeout=10), f2.result(timeout=10)
+        st = sched.stats()
+    assert st["admitted"] == 2 and st["running"] == 0
+    assert r1.phase_seconds["queue"] < 0.2
+    assert r2.phase_seconds["queue"] >= 0.3
+    assert st["slot_wait_s"] == pytest.approx(
+        r1.phase_seconds["queue"] + r2.phase_seconds["queue"])
+
+
 # -- trace-replayed multi-tenant smoke (the tier-1 gate) ---------------------
 
 @pytest.mark.usefixtures("lock_witness")
@@ -522,6 +590,12 @@ def test_trace_replayed_multitenant_smoke():
         assert np.allclose(np.asarray(fused), ref, rtol=1e-5,
                            atol=1e-5), tr.tenant
     assert len(edge.scheduler.admission_order()) == k
+    m = edge.metrics()
+    assert m["rounds_admitted"] == k and m["rounds_running"] == 0
+    assert m["slot_wait_s"] >= 0.0
+    assert m["accepted"] == k * n
+    assert all(results[t][1].phase_seconds["queue"] >= 0.0
+               for t in tenants)
 
 
 # -- benchmark smoke (tier-1 wiring) -----------------------------------------
