@@ -27,14 +27,18 @@ Wire containers:
     ``UpdateStore.write`` accepts it directly (codes blob + ``.scale``
     / ``.dim`` sidecars on disk).
   * :class:`CompressedBlock` — a stacked (c, P_padded) batch of
-    compressed rows, what ``UpdateStore.iter_chunks`` /
-    ``iter_arrivals`` yield for compressed entries and what the
-    engines' ``fuse_stream`` folds without host dequantization.
+    compressed rows, what the engines' ``fuse_stream`` folds without
+    host dequantization.
+  * :class:`RowBlock` — what ``UpdateStore.iter_chunks`` /
+    ``iter_arrivals`` yield: the store's rows (dense, or compressed
+    codes with their stacked scales) as they are, unstacked;
+    :func:`stack_block` turns one into a dense array or a
+    :class:`CompressedBlock` where a consumer needs one host array.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -112,9 +116,9 @@ class CompressedUpdate:
 
 @dataclasses.dataclass(frozen=True)
 class CompressedBlock:
-    """A stacked batch of compressed rows — the streaming wire format
-    ``UpdateStore.iter_chunks`` / ``iter_arrivals`` yield and the
-    engines' ``fuse_stream`` fold without host-side dequantization."""
+    """A stacked batch of compressed rows — what the engines'
+    ``fuse_stream`` fold without host-side dequantization, and what
+    :func:`stack_block` makes of a compressed :class:`RowBlock`."""
 
     codes: np.ndarray    # (rows, n_blocks * block) int8
     scales: np.ndarray   # (rows, n_blocks) fp32
@@ -137,6 +141,68 @@ class CompressedBlock:
         c, B = self.scales.shape
         v = self.codes.astype(np.float32).reshape(c, B, -1)
         return (v * self.scales[:, :, None]).reshape(c, -1)[:, : self.dim]
+
+
+@dataclasses.dataclass(frozen=True)
+class RowBlock:
+    """A block of store rows handed over as they are: what
+    ``UpdateStore.iter_chunks`` / ``iter_arrivals`` yield. ``arrays``
+    are the rows themselves — dense (P,) rows, or each compressed row's
+    (n_blocks * block,) int8 codes — not copied into one host array;
+    ``LocalEngine.fuse_stream`` assembles the (chunk, width) operand on
+    the device (small rows, for which a transfer each costs more than a
+    host copy, it stacks on the host). ``scales`` is the (rows,
+    n_blocks) fp32 stack of a compressed block's per-row scales, None
+    for a dense block. Consumers that need one host array call
+    :func:`stack_block`."""
+
+    arrays: Tuple[np.ndarray, ...]
+    scales: Optional[np.ndarray]
+    dim: int             # logical parameter count P
+
+    @property
+    def rows(self) -> int:
+        return len(self.arrays)
+
+    @property
+    def compressed(self) -> bool:
+        return self.scales is not None
+
+    @property
+    def width(self) -> int:
+        return int(self.arrays[0].shape[0])
+
+    @property
+    def dtype(self) -> np.dtype:
+        return self.arrays[0].dtype
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return self.rows, self.width
+
+    @property
+    def block(self) -> int:
+        return self.width // self.scales.shape[1]
+
+    @property
+    def nbytes(self) -> int:
+        extra = 0 if self.scales is None else self.scales.nbytes
+        return int(sum(a.nbytes for a in self.arrays) + extra)
+
+
+def stack_block(block):
+    """``block`` as one host array: a :class:`RowBlock`'s rows stacked
+    into a dense (c, P) array or a :class:`CompressedBlock` (a one-row
+    block is a view, not a copy); any other block is returned as it is.
+    The host copy for consumers that need it (``read_stacked``, the
+    distributed engine); the local engine's stream never calls it."""
+    if not isinstance(block, RowBlock):
+        return block
+    arrs = block.arrays
+    payload = arrs[0][None] if len(arrs) == 1 else np.stack(arrs)
+    if block.scales is None:
+        return payload
+    return CompressedBlock(codes=payload, scales=block.scales, dim=block.dim)
 
 
 def compress_update(vec, block: int = BLOCK) -> CompressedUpdate:

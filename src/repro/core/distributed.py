@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compress import BLOCK, CompressedBlock
+from repro.core.compress import BLOCK, CompressedBlock, stack_block
 from repro.core.fusion.base import FusionAlgorithm
 from repro.core.fusion.robust import GeometricMedian, Krum, Zeno
 from repro.core.local import StreamReport, _check_scale
@@ -416,7 +416,9 @@ class DistributedEngine:
     ) -> Tuple[jax.Array, StreamReport]:
         """Per-shard streaming ingest: fold (chunk, P) blocks (e.g. from
         ``UpdateStore.iter_chunks``) through ONE cached shard_map step
-        executable. Each block is staged host-side at O(chunk * P),
+        executable. Each block is staged host-side at O(chunk * P) (a
+        store :class:`repro.core.compress.RowBlock` is stacked by
+        ``stack_block``),
         device_put sharded over (client_axes, param_axis), and psum'd
         into a (P,)-sharded on-mesh accumulator — the dense (n, P)
         matrix never exists on the host. A block may be a
@@ -477,7 +479,9 @@ class DistributedEngine:
                 break
             rep.ingest_seconds += time.perf_counter() - t0
             with spans.span("engine.stage"):
-                block, w = item[0], item[1]
+                # the store's row blocks are stacked on the host here: the
+                # sharded device_put takes one host array per block
+                block, w = stack_block(item[0]), item[1]
                 scale = _check_scale(item[2]) if len(item) > 2 else None
                 if scale is not None and not weighted:
                     raise ValueError(

@@ -17,9 +17,12 @@ Compiled paths persist across rounds (the tentpole):
   * the memory-capped path is a single ``lax.scan`` over fixed-size
     client chunks (ONE executable) instead of the seed's Python loop of
     per-chunk jit dispatches;
-  * ``fuse_stream`` consumes (chunk, P) blocks straight off an
+  * ``fuse_stream`` consumes blocks straight off an
     ``UpdateStore.iter_chunks`` iterator — the dense (n, P) matrix never
-    exists on the host — accumulating with one cached step executable.
+    exists on the host, nor (for rows of ``_PLACE_MIN_ROW_BYTES`` and
+    more) does a (chunk, P) block: the store's rows cross to the device
+    as they are and are stacked there — accumulating with one cached
+    step executable.
 
 ``combine`` always runs OUTSIDE the compiled artifacts because FedAvgM /
 FedAdam carry python-side server state that must advance every round.
@@ -35,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.compress import BLOCK, CompressedBlock
+from repro.core.compress import BLOCK, CompressedBlock, RowBlock
 from repro.core.fusion.base import FusionAlgorithm
 from repro.kernels.fused_fusion.kernel import (
     weighted_sum_dequant_pallas,
@@ -43,10 +46,19 @@ from repro.kernels.fused_fusion.kernel import (
 )
 from repro.kernels.robust_fusion.kernel import topk_carve_pallas
 from repro.utils import spans
-from repro.utils.jitcache import CompiledCache, bucket_rows, fusion_cache_key
+from repro.utils.jitcache import (
+    CompiledCache, bucket_rows, fusion_cache_key, note_trace,
+)
 
 # fusions whose weighted-sum partial routes through the fused Pallas kernel
 _PALLAS_WSUM = ("fedavg", "gradavg", "iteravg", "fedavgm", "fedadam")
+
+# A row's own device_put costs a fixed time; stacking a block on the host
+# costs time per byte. On a v5e host a device_put costs 0.174 ms a row,
+# and stacking a 64 MiB block then moving it in one transfer runs at
+# 0.77 GB/s (PERF.md, section 6): rows from 0.174 ms x 0.77 GB/s, about
+# 133 kB, reach the device sooner one by one.
+_PLACE_MIN_ROW_BYTES = 133_000
 
 
 def _check_scale(scale) -> np.ndarray:
@@ -65,6 +77,55 @@ def _check_scale(scale) -> np.ndarray:
     return arr
 
 
+def _block_meta(block) -> Tuple[int, int, int, bool]:
+    """(rows, width, logical dim, compressed) of a stream block: a
+    :class:`RowBlock`, a :class:`CompressedBlock` or a dense (c, P)
+    array."""
+    if isinstance(block, RowBlock):
+        return block.rows, block.width, block.dim, block.compressed
+    if isinstance(block, CompressedBlock):
+        return block.rows, int(block.codes.shape[1]), block.dim, True
+    rows, width = block.shape
+    return int(rows), int(width), int(width), False
+
+
+def _as_rows(block) -> RowBlock:
+    """A stacked block's rows as host views (a device array is copied
+    to the host first)."""
+    if isinstance(block, CompressedBlock):
+        return RowBlock(arrays=tuple(np.asarray(block.codes)),
+                        scales=np.asarray(block.scales), dim=block.dim)
+    arr = np.asarray(block)
+    return RowBlock(arrays=tuple(arr), scales=None, dim=int(arr.shape[1]))
+
+
+@jax.jit
+def _assemble_rows(*rows):
+    """``chunk`` (width,) device rows -> one (chunk, width) operand. jit
+    keeps one executable per (chunk, width, dtype); it is cold only
+    where the stream step of that chunk, width and dtype is cold too, so
+    ``CompiledCache.misses`` still flags any round that compiles."""
+    note_trace()
+    return jnp.stack(rows)
+
+
+class _ZeroRows:
+    """One device-resident zero row per (width, dtype): the pad of every
+    ragged block, so no ragged size compiles or copies anything."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._rows: dict = {}  # guarded-by: _lock
+
+    def get(self, width: int, dtype: np.dtype):
+        with self._lock:
+            row = self._rows.get((width, dtype.str))
+            if row is None:
+                row = jax.device_put(np.zeros((width,), dtype))
+                self._rows[(width, dtype.str)] = row
+        return row
+
+
 @dataclasses.dataclass
 class StreamReport:
     """Phase accounting for one streamed aggregation."""
@@ -76,6 +137,9 @@ class StreamReport:
     compute_seconds: float = 0.0
     n_rows: int = 0
     n_blocks: int = 0
+    # rows that crossed to the device as the store's rows, with no host
+    # stack (the operand assembled on the device)
+    rows_placed: int = 0
     chunk_rows: int = 0
     # actual payload bytes ingested (pre-padding; codes + scales for
     # compressed blocks) — what RoundReport.bytes_ingested reports
@@ -108,6 +172,7 @@ class LocalEngine:
         # this engine, and one round's warm fold must not read another
         # round's cold compile time (or vice versa)
         self._tls = threading.local()
+        self._zero_rows = _ZeroRows()
 
     @property
     def last_compile_seconds(self) -> float:
@@ -211,9 +276,17 @@ class LocalEngine:
         Blocks are ``(updates, weights)`` or ``(updates, weights, scale)``
         — the optional NUMERIC (c,) ``scale`` multiplies the EFFECTIVE
         weights, so staleness discounting bites even for fusions (IterAvg)
-        that remap client weights. ``updates`` is a dense (c, P) array OR
-        a :class:`repro.core.compress.CompressedBlock` (int8 codes + fp32
-        per-block scales): compressed blocks fold WITHOUT host
+        that remap client weights. ``updates`` is a
+        :class:`repro.core.compress.RowBlock` (the store's rows, what
+        ``iter_chunks`` yields), a dense (c, P) array, OR a
+        :class:`repro.core.compress.CompressedBlock` (int8 codes + fp32
+        per-block scales). A RowBlock's rows of ``_PLACE_MIN_ROW_BYTES``
+        and more are never stacked on the host: they cross to the device
+        in one batched ``device_put`` and one cached executable per
+        (chunk, width, dtype) assembles the (chunk, width) operand there
+        (``StreamReport.rows_placed`` counts them); smaller rows, for
+        which a transfer each costs more than a host copy, are stacked
+        on the host in one copy. Compressed blocks fold WITHOUT host
         dequantization — the pallas strategy folds the scales into the
         weighted-sum kernel, the jnp strategy into the einsum — and a
         single round may freely mix dense and compressed blocks
@@ -221,7 +294,10 @@ class LocalEngine:
         cached step executable (the compile cache is keyed by payload
         dtype/shape), all folding into ONE shared (P,) fp32 accumulator.
         ``chunk_rows`` pins the step
-        executable's row count (undersized blocks are zero-weight padded):
+        executable's row count (undersized blocks are zero-weight padded:
+        placed rows on the device, from one cached device zero row per
+        (width, dtype), so no ragged size compiles anything; host-stacked
+        rows, the weights and the small scale rows on the host):
         pass the configured chunk so elastic/async rounds whose LAST block
         varies still hit one cached executable — the key
         ``is_warm_stream`` probes. Unset, the first block's size is used.
@@ -264,20 +340,23 @@ class LocalEngine:
             except StopIteration:
                 break
             rep.ingest_seconds += time.perf_counter() - t0
-            with spans.span("engine.stage"):
-                block, w = item[0], item[1]
+            block, w = item[0], item[1]
+            rows, width, bdim, compressed = _block_meta(block)
+            if chunk is None:
+                chunk = int(chunk_rows) if chunk_rows else rows
+            if rows < chunk and not isinstance(block, RowBlock):
+                # a ragged stacked block is padded the one way a
+                # RowBlock is (see _place)
+                block = _as_rows(block)
+            with spans.span("engine.stage") as stage:
                 scale = _check_scale(item[2]) if len(item) > 2 else None
                 if scale is not None and not weighted:
                     raise ValueError(
                         f"{fusion.name}: per-row staleness scales are "
                         "unsupported — order statistics cannot discount rows"
                     )
-                compressed = isinstance(block, CompressedBlock)
-                rows = block.rows if compressed else block.shape[0]
-                bdim = block.dim if compressed else block.shape[1]
-                if chunk is None:
+                if state is None:
                     dim = bdim
-                    chunk = int(chunk_rows) if chunk_rows else rows
                     rep.chunk_rows = chunk
                     state = self._stream_state(fusion, dim, n_hint, init)
                     sig = fusion.state_signature(dim, n_hint)
@@ -285,8 +364,13 @@ class LocalEngine:
                     raise ValueError(
                         f"fuse_stream: block dim {bdim} != stream dim {dim}"
                     )
+                if rows > chunk:
+                    raise ValueError(
+                        f"fuse_stream: block of {rows} rows exceeds "
+                        f"chunk_rows={chunk}"
+                    )
                 rep.ingest_bytes += int(block.nbytes)   # pre-padding payload
-                kind = ("q", block.codes.shape[1], block.block) if compressed \
+                kind = ("q", width, block.block) if compressed \
                     else ("d", np.dtype(block.dtype).str)
                 step = steps.get(kind)
                 if step is None:
@@ -297,8 +381,8 @@ class LocalEngine:
                     )
                     if compressed:
                         step, compile_s = self._stream_step_q(
-                            fusion, chunk, dim, block.codes.shape[1],
-                            block.block, sig, avals,
+                            fusion, chunk, dim, width, block.block, sig,
+                            avals,
                         )
                     else:
                         step, compile_s = self._stream_step(
@@ -309,28 +393,22 @@ class LocalEngine:
                     compile_total += compile_s
                     rep.compile_seconds = compile_total
                     self.last_compile_seconds = compile_total
-                if rows > chunk:
-                    raise ValueError(
-                        f"fuse_stream: block of {rows} rows exceeds "
-                        f"chunk_rows={chunk}"
-                    )
+                scales = block.scales if compressed else None
+                placed = 0
+                if isinstance(block, RowBlock):
+                    payload, placed = self._place(block.arrays, chunk)
+                    rep.rows_placed += placed
+                else:
+                    payload = block.codes if compressed else block
+                stage.set_metadata(rows_placed=placed)
                 if rows < chunk:           # ragged final block: zero-weight pad
                     wpad = np.zeros((chunk,), np.float32)
                     wpad[:rows] = w
                     w = wpad
-                    if compressed:
-                        qpad = np.zeros((chunk, block.codes.shape[1]), np.int8)
-                        qpad[:rows] = block.codes
-                        spad = np.zeros(
-                            (chunk, block.scales.shape[1]), np.float32
-                        )
-                        spad[:rows] = block.scales
-                        block = CompressedBlock(codes=qpad, scales=spad,
-                                                dim=dim)
-                    else:
-                        padded = np.zeros((chunk, dim), block.dtype)
-                        padded[:rows] = block
-                        block = padded
+                    if compressed:         # scale rows are padded on the host
+                        spad = np.zeros((chunk, scales.shape[1]), np.float32)
+                        spad[:rows] = scales
+                        scales = spad
                 if weighted:
                     w = np.array(
                         fusion.effective_weights(jnp.asarray(w, jnp.float32))
@@ -346,9 +424,9 @@ class LocalEngine:
             t0 = time.perf_counter()
             with sem, spans.span("engine.step"):
                 if compressed:
-                    state = step(block.codes, block.scales, w, *state)
+                    state = step(payload, scales, w, *state)
                 else:
-                    state = step(block, w, *state)
+                    state = step(payload, w, *state)
                 if device_sem is not None:
                     # dispatch is async: holding the semaphore only
                     # bounds execution if we wait for it (single-tenant
@@ -372,6 +450,27 @@ class LocalEngine:
             fused = jax.block_until_ready(fusion.finalize(state))  # lint: disable=sync-under-sem -- deliberate: the permit must cover device EXECUTION, not just dispatch (PR 5's device_concurrency contract)
         rep.compute_seconds += time.perf_counter() - t0
         return fused, rep
+
+    def _place(self, arrays, chunk: int):
+        """The (chunk, width) step operand from host rows, and how many
+        rows reached the device with no host stack. Rows of
+        ``_PLACE_MIN_ROW_BYTES`` and more cross in one batched
+        ``device_put`` and one cached executable per (chunk, width,
+        dtype) stacks them on the device, filling a ragged block's
+        missing rows with the cached device zero row, so every ragged
+        size reuses it; a one-row chunk crosses as a (1, width) view
+        with no assembly. Smaller rows are stacked (and padded) on the
+        host in one copy and cross with the step call."""
+        if chunk == 1:
+            return jax.device_put(arrays[0][None]), 1
+        width, dtype = int(arrays[0].shape[0]), np.dtype(arrays[0].dtype)
+        if arrays[0].nbytes < _PLACE_MIN_ROW_BYTES:
+            out = np.zeros((chunk, width), dtype)
+            np.stack(arrays, out=out[:len(arrays)])
+            return out, 0
+        placed = jax.device_put(list(arrays))
+        placed += [self._zero_rows.get(width, dtype)] * (chunk - len(placed))
+        return _assemble_rows(*placed), len(arrays)
 
     @staticmethod
     def _stream_state(fusion, dim, n_hint, init):

@@ -25,9 +25,14 @@ with pre-tenant spools) and every other tenant under
 ``spool_dir/<tenant>/``.
 
 The aggregator-side read path is STREAMING-first: ``iter_chunks`` hands
-the engine fixed-size (chunk, P) blocks with the next block prefetched on
-a reader thread (double buffering), so a round never materializes the
-dense (n, P) matrix on the host — peak ingest allocation is O(chunk * P).
+the engine blocks of at most ``chunk`` rows with the next block prefetched
+on a reader thread (double buffering), so a round never materializes the
+dense (n, P) matrix on the host. A block is a
+:class:`repro.core.compress.RowBlock`: the rows as the store holds them
+(the memory backend's read-only views), never copied into one host
+array by the store; ``LocalEngine.fuse_stream`` assembles the (chunk, P)
+operand on the device (rows too small to be worth a transfer each it
+stacks on the host).
 ``iter_arrivals`` is the arrival-driven variant (the async-round
 substrate): it yields a block as soon as ``chunk_rows`` NEW updates land,
 snapshot-free, with the caller's threshold/timeout gate deciding when the
@@ -49,11 +54,12 @@ External writers route compressed blobs the same way (codes blob +
 ``.scale`` next to it); ``ingest_external`` / ``SpoolTailer`` move and
 register the sidecar set atomically-enough (blob last). The streaming
 read paths — ``iter_chunks`` / ``iter_arrivals`` — yield compressed
-rows as :class:`repro.core.compress.CompressedBlock` WITHOUT host-side
-dequantization (the engines fold the scales in-kernel); a round may mix
-dense and compressed entries (stragglers may be uncompressed), in which
-case each yielded block is homogeneous: rows are grouped by payload
-kind, only the per-kind final block is ragged. Quota/byte accounting
+rows as a :class:`repro.core.compress.RowBlock` of their codes with the
+scales stacked, WITHOUT host-side dequantization (the engines fold the
+scales in-kernel); a round may mix dense and compressed entries
+(stragglers may be uncompressed), in which case each yielded block is
+homogeneous: rows are grouped by payload kind, only the per-kind final
+block is ragged. Quota/byte accounting
 (``tenant_bytes``, ``StoreStats.bytes*``, ``TenantQuota.max_bytes``)
 counts the REAL compressed size (codes + scales), not the logical fp32
 size — compressing buys actual quota headroom.
@@ -93,7 +99,9 @@ from typing import (
 
 import numpy as np
 
-from repro.core.compress import CompressedBlock, CompressedUpdate
+from repro.core.compress import (
+    CompressedBlock, CompressedUpdate, RowBlock, stack_block,
+)
 from repro.utils import spans
 from repro.utils.pytree import tree_to_flat_vector
 
@@ -895,9 +903,11 @@ class UpdateStore:
         tenant: Optional[str] = None,
     ) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
         """Yield (updates, weights (c,) fp32) blocks from ``tenant``'s
-        partition (``None``: whole spool) — updates is a dense (c, P)
-        stored-dtype array, or a :class:`CompressedBlock` for int8
-        block-quantized rows (no host-side dequantization). c ==
+        partition (``None``: whole spool) — updates is a
+        :class:`RowBlock` of c stored-dtype rows, or of int8
+        block-quantized codes with their scales (no host-side
+        dequantization, no host stack: ``stack_block`` makes one host
+        array where a consumer needs it). c ==
         chunk_rows except for ragged final blocks; in a MIXED
         dense/compressed partition each chunk splits into one
         homogeneous block per payload kind (see ``_load_block``).
@@ -905,8 +915,10 @@ class UpdateStore:
         With ``prefetch`` a reader thread stages block k+1 while the
         engine consumes block k (double buffering): at most two blocks are
         resident, so peak host-side ingest memory is O(2 * chunk * P)
-        regardless of n. The iterator works over a snapshot of the client
-        index — updates written after the call don't shift the blocks.
+        regardless of n (the memory backend's rows are views of the
+        spool, so a block adds none). The iterator works over a snapshot
+        of the client index — updates written after the call don't shift
+        the blocks.
         """
         with self._lock:
             keys = self._keys(tenant)
@@ -976,10 +988,12 @@ class UpdateStore:
         versions_out: Optional[Dict[str, int]] = None,
         keys_out: Optional[List[_Key]] = None,
     ) -> Optional[List[Tuple[object, np.ndarray, List[_Key]]]]:
-        """Stack one batch of index keys into homogeneous sub-blocks
+        """Load one batch of index keys as homogeneous sub-blocks
         ``[(payload, (c,) weights, loaded keys), ...]`` where payload is
-        a dense (c, P) stored-dtype array or a :class:`CompressedBlock`
-        — blob reads happen lock-free, stats update under the lock.
+        a :class:`RowBlock` of the rows as read — the memory backend's
+        read-only views (no host copy), or the disk backend's freshly
+        loaded blobs — with a compressed block's per-row scales stacked;
+        blob reads happen lock-free, stats update under the lock.
 
         Rows are GROUPED by payload kind (dense dtype+width, or
         compressed codes-width+block): an all-dense or all-compressed
@@ -1024,16 +1038,19 @@ class UpdateStore:
             total_bytes = 0
             per_tenant: Dict[str, Tuple[int, int]] = {}
             for kind, (ups, ws, loaded) in groups.items():
+                # the rows as read: no host copy of the payload (the
+                # engine assembles the operand on the device); only the
+                # small per-row scale vectors are stacked
                 if kind[0] == "q":
-                    payload: object = CompressedBlock(
-                        codes=np.stack([cu.codes for cu in ups]),
+                    payload = RowBlock(
+                        arrays=tuple(cu.codes for cu in ups),
                         scales=np.stack([cu.scales for cu in ups]),
                         dim=kind[3],
                     )
-                    nbytes = payload.nbytes
                 else:
-                    payload = np.stack(ups)
-                    nbytes = payload.nbytes
+                    payload = RowBlock(arrays=tuple(ups), scales=None,
+                                       dim=kind[2])
+                nbytes = payload.nbytes
                 out.append((payload, np.asarray(ws, np.float32), loaded))
                 total_bytes += nbytes
                 row_bytes = nbytes // max(len(ups), 1)
@@ -1066,8 +1083,8 @@ class UpdateStore:
     ) -> Iterator[Tuple[np.ndarray, np.ndarray, List[str]]]:
         """Arrival-driven streaming read — the async-round substrate.
 
-        Yields (block, (c,) weights, client_ids) — block a dense (c, P)
-        array or a :class:`CompressedBlock` (mixed partitions split each
+        Yields (block, (c,) weights, client_ids) — block a
+        :class:`RowBlock`, dense or compressed (mixed partitions split each
         chunk into homogeneous per-kind blocks) — as soon as
         ``chunk_rows`` NEW updates have landed in ``tenant``'s partition
         (``None``: whole spool), without snapshotting the index up
@@ -1141,6 +1158,7 @@ class UpdateStore:
         for block, w in self.iter_chunks(
             chunk_rows=1 << 62, prefetch=False, tenant=tenant
         ):
+            block = stack_block(block)
             if isinstance(block, CompressedBlock):
                 block = block.dequantize()
             ups.append(block)

@@ -25,6 +25,7 @@ from repro.core import (
     Workload,
     get_fusion,
 )
+from repro.core.compress import stack_block
 from repro.launch.mesh import make_mesh
 
 RNG = np.random.default_rng(31)
@@ -174,7 +175,7 @@ def test_late_writes_land_during_inflight_stream():
     assert all(b.shape[0] == chunk for b, _, _ in got[:-1])
     # the stream saw the count GROW while in flight: arrival-driven
     assert seen_counts[0] < n and max(seen_counts) == n
-    stacked = np.concatenate([b for b, _, _ in got])
+    stacked = np.concatenate([stack_block(b) for b, _, _ in got])
     ws = np.concatenate([wb for _, wb, _ in got])
     np.testing.assert_allclose(
         _fedavg(stacked, ws), _fedavg(u, w), rtol=1e-4, atol=1e-5

@@ -9,11 +9,13 @@ from repro.core.compress import (
     CompressedBlock,
     CompressedUpdate,
     ErrorFeedbackCompressor,
+    RowBlock,
     compress_update,
     compressed_bytes,
     compression_ratio,
     dequantize,
     quantize,
+    stack_block,
 )
 from repro.core.fusion import FedAvg
 from repro.core.local import LocalEngine
@@ -150,7 +152,9 @@ def test_store_roundtrips_compressed_updates(tmp_path):
         assert (n, p, dtype) == (1, 5003, np.dtype(np.int8))
         blocks = list(store.iter_chunks(4))
         assert len(blocks) == 1
-        assert isinstance(blocks[0][0], CompressedBlock)
+        block = blocks[0][0]
+        assert isinstance(block, RowBlock) and block.compressed
+        assert isinstance(stack_block(block), CompressedBlock)
 
 
 def test_store_quota_counts_compressed_bytes():

@@ -374,7 +374,7 @@ def test_mid_read_eviction_skips_row_instead_of_folding(tmp_path,
     assert evicted
     (block, w, loaded), = blk                   # one dense sub-block
     assert block.shape[0] == 1                  # c0's row was skipped
-    np.testing.assert_allclose(block[0], 2.0)   # only c1 folded
+    np.testing.assert_allclose(block.arrays[0], 2.0)   # only c1 folded
     assert loaded == [("default", "c1")]
 
 

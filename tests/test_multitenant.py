@@ -32,6 +32,7 @@ from repro.core import (
     SpoolTailer,
     UpdateStore,
 )
+from repro.core.compress import stack_block
 
 RNG = np.random.default_rng(123)
 
@@ -116,7 +117,7 @@ def test_store_per_tenant_streams_and_consume():
     np.testing.assert_array_equal(stacked, u[:3])
     np.testing.assert_array_equal(ws, w[:3])
     blocks = list(store.iter_chunks(2, tenant="B"))
-    got = np.concatenate([b for b, _ in blocks])
+    got = np.concatenate([stack_block(b) for b, _ in blocks])
     np.testing.assert_array_equal(got, u[3:])
     # arrival timestamps filter too
     assert set(store.arrival_times("A")) == {"c0", "c1", "c2"}

@@ -20,6 +20,8 @@ import numpy as np
 import pytest
 
 from repro.core import AggregationService, LocalEngine, UpdateStore
+from repro.core import local as local_engine
+from repro.core.compress import compress_update, stack_block
 from repro.core.distributed import DistributedEngine
 from repro.core.fusion import REGISTRY, get_fusion
 from repro.kernels.fused_fusion.kernel import weighted_sum_pallas
@@ -84,6 +86,196 @@ def test_stream_bf16_blocks_match_fp32_reference():
     ref = np.asarray(LocalEngine().fuse(get_fusion("fedavg"), u32, w))
     np.testing.assert_allclose(np.asarray(fused), ref, rtol=2e-2, atol=2e-2)
     assert np.asarray(fused).dtype == np.float32
+
+
+# -- row staging: store rows reach the device with no host stack -------------
+
+
+def _host_stacked(arrays, chunk):
+    """The host-stacked reference for ``LocalEngine._place``: the rows
+    stacked into one host array, a ragged block padded with zero rows."""
+    out = np.zeros((chunk,) + arrays[0].shape, arrays[0].dtype)
+    out[:len(arrays)] = np.stack(arrays)
+    return out, 0
+
+
+def _staging_case(case, spool):
+    """(fusion, store, chunk, n_hint, init, gamma) for one staging case;
+    ``gamma`` turns the blocks into a staleness-scaled async round."""
+    p = 5000
+    store = UpdateStore(backend="disk", spool_dir=spool) \
+        if case == "disk_int8" else UpdateStore()
+    fusion, chunk, n_hint = get_fusion("fedavg"), 4, None
+    init = gamma = None
+    n = {"one_row": 3, "ragged_1": 9, "ragged_2": 10,
+         "ragged_3": 11}.get(case, 10)
+    u = RNG.normal(size=(n, p)).astype(np.float32)
+    w = RNG.uniform(1, 5, size=(n,)).astype(np.float32)
+    for i in range(n):
+        row = u[i]
+        if case in ("int8", "disk_int8") or (
+                case == "mixed" and i % 3 != 2):
+            row = compress_update(u[i])
+        store.write(f"c{i:02d}", row, weight=float(w[i]))
+    if case == "one_row":
+        chunk = 1
+    elif case == "trimmed_carve":
+        fusion, n_hint = get_fusion("trimmedmean"), n
+    elif case == "stale_async":
+        gamma = 0.5
+        init = (RNG.normal(size=(p,)).astype(np.float32), np.float32(3.0))
+    return fusion, store, chunk, n_hint, init, gamma
+
+
+def _staged_blocks(store, chunk, gamma):
+    for i, (block, w) in enumerate(store.iter_chunks(chunk)):
+        if gamma is None:
+            yield block, w
+        else:   # per-row staleness: gamma ** age, ages varying by row
+            ages = (np.arange(block.rows) + i) % 3
+            yield block, w, (gamma ** ages).astype(np.float32)
+
+
+@pytest.mark.parametrize("case", [
+    "dense_fp32", "int8", "mixed", "one_row", "ragged_1", "ragged_2",
+    "ragged_3", "trimmed_carve", "stale_async", "disk_int8",
+])
+def test_row_staging_matches_host_stacked(case, monkeypatch, tmp_path):
+    """Rows placed one by one and assembled on the device fold to exactly
+    what the host-stacked, host-padded operand folds to: the same fused
+    vector and the same carried state, bit for bit."""
+    monkeypatch.setattr(local_engine, "_PLACE_MIN_ROW_BYTES", 0)
+    fusion, store, chunk, n_hint, init, gamma = _staging_case(
+        case, str(tmp_path))
+    eng = LocalEngine(strategy="pallas")
+    ref = LocalEngine(strategy="pallas")
+    monkeypatch.setattr(ref, "_place", _host_stacked)
+    got, rep = eng.fuse_stream(
+        fusion, _staged_blocks(store, chunk, gamma), init=init,
+        chunk_rows=chunk, n_hint=n_hint)
+    want, rep_ref = ref.fuse_stream(
+        fusion, _staged_blocks(store, chunk, gamma), init=init,
+        chunk_rows=chunk, n_hint=n_hint)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert len(rep.acc_state) == len(rep_ref.acc_state)
+    for a, b in zip(rep.acc_state, rep_ref.acc_state):
+        np.testing.assert_array_equal(a, b)
+    assert rep.rows_placed == rep.n_rows == store.count()
+    assert rep.ingest_bytes == rep_ref.ingest_bytes
+
+
+def test_row_staging_ragged_blocks_compile_nothing(monkeypatch):
+    """Ragged final blocks of every size reuse the one assembly and the
+    one step executable: the compile cache misses no more and nothing
+    is traced after the first round, and every row reached the device
+    with no host stack."""
+    monkeypatch.setattr(local_engine, "_PLACE_MIN_ROW_BYTES", 0)
+    eng = LocalEngine(strategy="pallas")
+    f = get_fusion("fedavg")
+    p, chunk = 3001, 4     # a width no other test assembles
+    misses = traces = None
+    for n in (8, 9, 10, 11, 5, 1):
+        u = RNG.normal(size=(n, p)).astype(np.float32)
+        w = RNG.uniform(1, 5, size=(n,)).astype(np.float32)
+        store = UpdateStore()
+        for i in range(n):
+            store.write(f"c{i:02d}", u[i], weight=float(w[i]))
+        fused, rep = eng.fuse_stream(f, store.iter_chunks(chunk),
+                                     chunk_rows=chunk)
+        ref = np.einsum("np,n->p", u, w) / (w.sum() + 1e-6)
+        np.testing.assert_allclose(np.asarray(fused), ref, rtol=1e-4,
+                                   atol=1e-5)
+        assert rep.rows_placed == rep.n_rows == n
+        if misses is None:
+            misses, traces = eng.cache.misses, jitcache.trace_count()
+            assert misses == 1
+        assert eng.cache.misses == misses
+        assert jitcache.trace_count() == traces
+        assert rep.compile_seconds == 0.0 or n == 8
+
+
+def test_stage_span_counts_placed_rows(tmp_path, monkeypatch):
+    """A trace shows how often placement engaged: each
+    ``repro.engine.stage`` span carries its block's ``rows_placed``."""
+    import glob
+
+    monkeypatch.setattr(local_engine, "_PLACE_MIN_ROW_BYTES", 0)
+    store = UpdateStore()
+    for i in range(7):
+        store.write(f"c{i}", RNG.normal(size=(300,)).astype(np.float32))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, rep = LocalEngine(strategy="jnp").fuse_stream(
+            get_fusion("fedavg"), store.iter_chunks(4), chunk_rows=4)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    placed = [dict(ev.stats).get("rows_placed")
+              for plane in data.planes for line in plane.lines
+              for ev in line.events if ev.name == "repro.engine.stage"]
+    assert sorted(placed) == [3, 4] and rep.rows_placed == 7
+
+
+@pytest.mark.parametrize("p", [1000, 50_000])
+def test_row_size_picks_the_staging(p):
+    """Rows under ``_PLACE_MIN_ROW_BYTES`` are stacked on the host in one
+    copy, larger ones placed one by one; both fold to the same bits."""
+    chunk, n = 4, 7
+    u = RNG.normal(size=(n, p)).astype(np.float32)
+    store = UpdateStore()
+    for i in range(n):
+        store.write(f"c{i}", u[i], weight=float(i + 1))
+    f = get_fusion("fedavg")
+    got, rep = LocalEngine(strategy="jnp").fuse_stream(
+        f, store.iter_chunks(chunk), chunk_rows=chunk)
+    ref = LocalEngine(strategy="jnp")
+    ref._place = _host_stacked
+    want, _ = ref.fuse_stream(f, store.iter_chunks(chunk), chunk_rows=chunk)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    small = p * 4 < local_engine._PLACE_MIN_ROW_BYTES
+    assert rep.rows_placed == (0 if small else n)
+
+
+def test_store_blocks_share_the_store_rows(monkeypatch):
+    """The memory backend's blocks are the store's rows, not copies, and
+    stay read-only; a row consumed by a racing ``remove`` mid-load is
+    skipped without a crash."""
+    p = 4100
+    store = UpdateStore()
+    dense = RNG.normal(size=(3, p)).astype(np.float32)
+    for i in range(3):
+        store.write(f"d{i}", dense[i])
+        store.write(f"q{i}", compress_update(dense[i]))
+    orig = UpdateStore._read_versioned
+    raced = []
+
+    def read_then_race(self, key):
+        out = orig(self, key)
+        if key == ("default", "d0") and not raced:
+            self.remove(["d1"])       # a concurrent round consumes d1
+            raced.append(True)
+        return out
+
+    monkeypatch.setattr(UpdateStore, "_read_versioned", read_then_race)
+    with store._lock:
+        keys = store._keys("default")
+    loaded = []
+    blocks = store._load_block(keys, keys_out=loaded)
+    assert raced
+    assert ("default", "d1") not in loaded and len(loaded) == 5
+    (dblk, _, dkeys), (qblk, _, qkeys) = blocks
+    assert not dblk.compressed and qblk.compressed
+    assert [k for _, k in dkeys] == ["d0", "d2"]
+    for block, ks in ((dblk, dkeys), (qblk, qkeys)):
+        for arr, key in zip(block.arrays, ks):
+            held = store._mem[key][0]
+            held = held.codes if block.compressed else held
+            assert np.shares_memory(arr, held)
+            assert not arr.flags.writeable
+    np.testing.assert_array_equal(stack_block(dblk), dense[[0, 2]])
+    assert stack_block(qblk).codes.shape == (3, qblk.width)
 
 
 # -- shape-bucketed cache: zero re-traces -------------------------------------
@@ -219,7 +411,7 @@ def test_store_iter_chunks_ragged_and_peak_tracking():
                     weight=float(i + 1))
     blocks = list(store.iter_chunks(chunk))
     assert [b.shape[0] for b, _ in blocks] == [4, 4, 3]
-    stacked = np.concatenate([b for b, _ in blocks])
+    stacked = np.concatenate([stack_block(b) for b, _ in blocks])
     ref, wref = store.read_stacked()
     np.testing.assert_array_equal(stacked, ref)
     np.testing.assert_array_equal(
