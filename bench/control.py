@@ -50,8 +50,9 @@ def main(argv=None) -> int:
         run.peaks = peaks
         program = harness.checks(run)
         rounds, _ = harness.judged(run)
-        control = reference.check_rounds(seed, cell.config, rounds,
-                                         control=True)
+        control = reference.check_rounds(
+            seed, cell.config, [(inc, None) for inc, _, _ in rounds],
+            control=True)
         print(json.dumps({
             "workload": cell.name, "seed": seed, "device": device,
             "rounds": len(rounds), "program": program,

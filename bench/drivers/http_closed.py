@@ -17,6 +17,9 @@ import threading
 import time
 from pathlib import Path
 
+# each round's uploads are recorded with it, so it can be checked as it ends
+CHECKS_EACH_ROUND = True
+
 ROOT = Path(__file__).resolve().parents[2]
 
 

@@ -22,6 +22,9 @@ import threading
 import time
 from pathlib import Path
 
+# uploads are recorded only at the run's end: no round is checked as it ends
+CHECKS_EACH_ROUND = False
+
 ROOT = Path(__file__).resolve().parents[2]
 
 

@@ -15,6 +15,9 @@ from __future__ import annotations
 import threading
 import time
 
+# each round's writes are recorded with it, so it can be checked as it ends
+CHECKS_EACH_ROUND = True
+
 
 def run(ctx):
     from bench import harness, payloads, schedule, sut, tracing

@@ -118,6 +118,9 @@ class RoundRec:
     phase: Dict[str, float] = dataclasses.field(default_factory=dict)
     n_clients: int = 0
     fused: Optional[np.ndarray] = None
+    # (gap, scale) of a round checked as it ended (a ranged
+    # configuration's, ``sut.Session``); its fused vector is dropped then
+    checked: Optional[Tuple[float, float]] = None
     error: Optional[str] = None
 
 
@@ -148,9 +151,19 @@ class Run:
     fold_steps: List[list] = dataclasses.field(default_factory=list)
     trace: Optional[object] = None   # tracing.Reduced of the traced run
     peaks: Optional[dict] = None     # this device's row of peaks.json
+    # when the benchmark checked rounds as they ended: no part of the
+    # window's time (``measured``, ``tracing.Reduced.paused``)
+    paused: List[Tuple[float, float]] = dataclasses.field(
+        default_factory=list)
 
     def in_window(self, t: Optional[float]) -> bool:
         return t is not None and self.window[0] <= t < self.window[1]
+
+    def measured(self, a: float, b: float) -> float:
+        """Seconds from ``a`` to ``b``, less the checks between them."""
+        from bench import tracing
+
+        return (b - a) - tracing.covered(tracing.merge(self.paused), a, b)
 
     def window_rounds(self) -> List[RoundRec]:
         """Rounds started in the window. Each runs to its end, so how
@@ -183,32 +196,48 @@ def close_times(rounds: Sequence[RoundRec], uploads: Sequence[UploadRec]):
 # -- correctness ---------------------------------------------------------------
 
 
+def included_pairs(r: RoundRec, by_cid: Dict[str, UploadRec]
+                   ) -> Optional[List[Tuple[int, int]]]:
+    """The round's included uploads as ``(key, weight)`` pairs; None
+    where the round's report disagrees with what it consumed, or it
+    consumed an upload that was never sent."""
+    if r.n_clients != len(r.included) \
+            or any(c not in by_cid for c in r.included):
+        return None
+    return [(by_cid[c].key, by_cid[c].weight) for c in r.included]
+
+
 def judged(run: Run) -> Tuple[list, int]:
     """The rounds started in the window as ``(included (key, weight)
-    pairs, fused)``, and the count of rounds that failed."""
+    pairs, fused, checked)``, and the count of rounds that failed."""
     by_cid = {u.cid: u for u in run.uploads}
     failed = sum(1 for r in run.rounds if r.error is not None)
     out = []
     for r in run.window_rounds():
         if r.error is not None:
             continue
-        if r.n_clients != len(r.included) \
-                or any(c not in by_cid for c in r.included):
+        included = included_pairs(r, by_cid)
+        if included is None:
             failed += 1
             continue
-        out.append(([(by_cid[c].key, by_cid[c].weight)
-                     for c in r.included], r.fused))
+        out.append((included, r.fused, r.checked))
     return out, failed
 
 
 def checks(run: Run) -> Dict[str, float]:
-    """The compared numbers: each is judged against ``LIMITS``."""
+    """The compared numbers: each is judged against ``LIMITS``. Rounds
+    checked as they ended bring their ``(gap, scale)``; the others are
+    checked here."""
     from bench import reference
 
     rounds, failed = judged(run)
-    errs = reference.check_rounds(run.ctx.seed, run.ctx.cell.config, rounds)
+    later = [(inc, fused) for inc, fused, done in rounds if done is None]
+    errs = reference.check_rounds(run.ctx.seed, run.ctx.cell.config,
+                                  later)["rel_errors"]
+    errs += [reference.rel_error(*done) for _, _, done in rounds
+             if done is not None]
     return {
-        "max_rel_err": errs["max_rel_err"],
+        "max_rel_err": max(errs, default=float("inf")),
         "lost_uploads": lost_uploads(run),
         "rounds_failed": failed,
     }
@@ -334,7 +363,12 @@ def log_host_memory(stage: str) -> None:
 
 def run_cell(ctx: Context, device: dict, peaks: Optional[dict]) -> dict:
     """Drive the cell, free the program, then check and read it."""
+    from bench import manifest
+
     driver = load_module(ctx.cell.driver_path)
+    problems = manifest.check_problems(ctx.cell.config, driver)
+    if problems:
+        raise ValueError("; ".join(problems))
     run = driver.run(ctx)
     run.peaks = peaks
     gc.collect()

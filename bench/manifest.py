@@ -5,11 +5,15 @@ text; an empty list means it holds. The rules: names and units use only
 their allowed characters; each metric's ``moves`` target is reported in
 each cell of the metric; every cell reports ``setup_s``, another
 end-to-end metric and a per-layer metric; every cell's configuration,
-traffic, driver and metric files resolve by name.
+traffic, driver and metric files resolve by name; a configuration's
+``"mesh"`` names the mesh engines' axes and covers the cell's chips; a
+configuration whose rounds are checked as they end has a driver that
+can.
 """
 from __future__ import annotations
 
 import json
+import math
 import re
 from pathlib import Path
 from typing import List
@@ -25,6 +29,42 @@ KEYS = {
     "per_layer": {"name", "unit", "better", "source", "layer", "moves"},
 }
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+MESH_AXES = ("pod", "data", "model")   # the axes DistributedEngine knows
+
+
+def mesh_problems(config: dict, chips: int) -> List[str]:
+    """What is wrong with a configuration's ``"mesh"`` for a cell of
+    ``chips`` chips; nothing where it has none."""
+    mesh = config.get("mesh")
+    if mesh is None:
+        return []
+    if not isinstance(mesh, dict) or not mesh:
+        return [f"mesh {mesh!r} is not a map of axis sizes"]
+    out = [f"mesh axis {a!r} is not one of {MESH_AXES}"
+           for a in mesh if a not in MESH_AXES]
+    sizes = list(mesh.values())
+    if not all(isinstance(v, int) and v >= 1 for v in sizes):
+        out.append(f"mesh sizes {sizes} are not whole numbers from 1")
+    elif math.prod(sizes) != chips:
+        out.append(f"mesh {mesh} covers {math.prod(sizes)} chips, the "
+                   f"cell asks for {chips}")
+    return out
+
+
+def check_problems(config: dict, driver) -> List[str]:
+    """A ranged configuration's rounds are checked as each ends
+    (``sut.Session``), so its driver, a module of ``bench/drivers``,
+    must hand each round to the session with the uploads it folded:
+    one that says so with ``CHECKS_EACH_ROUND = True``."""
+    from bench import payloads
+
+    kind = config.get("payload", {}).get("kind")
+    if kind in payloads.RANGED and \
+            not getattr(driver, "CHECKS_EACH_ROUND", False):
+        name = Path(getattr(driver, "__file__", str(driver))).stem
+        return [f"{kind} rounds are checked as each ends, which the "
+                f"driver {name!r} cannot do"]
+    return []
 
 
 def _line(text) -> bool:
@@ -104,14 +144,27 @@ def problems(manifest: dict, root: Path) -> List[str]:
             out.append(f"{name}: unknown config {w['config']!r}")
         if w["chips"] not in (1, 4):
             out.append(f"{name}: chips must be 1 or 4")
+        entry, config = configs.get(w["config"]), None
+        if entry is not None and (root / entry["file"]).is_file():
+            with open(root / entry["file"]) as f:
+                config = json.load(f)
+            out += [f"{name}: {p}"
+                    for p in mesh_problems(config, w["chips"])]
         traffic = root / "bench" / "traffic" / f"{w['traffic']}.json"
         if not traffic.is_file():
             out.append(f"{name}: no traffic file {traffic.name}")
         else:
             with open(traffic) as f:
-                driver = json.load(f).get("driver", "")
-            if not (root / "bench" / "drivers" / f"{driver}.py").is_file():
-                out.append(f"{name}: no driver {driver!r}")
+                spec = json.load(f)
+            driver = root / "bench" / "drivers" / \
+                f"{spec.get('driver', '')}.py"
+            if not driver.is_file():
+                out.append(f"{name}: no driver {spec.get('driver')!r}")
+            elif config is not None:
+                from bench import harness
+
+                out += [f"{name}: {p}" for p in check_problems(
+                    config, harness.load_module(driver))]
         reported = [m["name"] for m in manifest["end_to_end"]
                     if name in _cells(m, cells)]
         if "setup_s" not in reported or len(reported) < 2:

@@ -1,8 +1,8 @@
 """device_idle_in_close_pct (%): the device's idle share inside the
 round-close intervals of the traced sub-window: 1 - (device-busy time
-within them) / (their length). A close interval runs from the round's
-close condition to its fused vector on the host; only those wholly
-inside the traced sub-window count."""
+within them, averaged over the devices) / (their length). A close
+interval runs from the round's close condition to its fused vector on
+the host; only those wholly inside the traced sub-window count."""
 
 
 def read(run):

@@ -1,7 +1,8 @@
 """wsum_roofline_pct (%): the weighted-sum kernel's share of the HBM
 roofline in the traced sub-window: the bytes its calls must move
 (bench/roofline.py, from the compiled fold step's operand shapes) over
-their device time from the trace, over 819 GB/s."""
+their device time from the trace, over 819 GB/s; over a mesh, the
+mean chip's share (``roofline.kernel_share``)."""
 
 from bench import roofline
 
@@ -9,11 +10,4 @@ KERNEL = "weighted_sum_pallas"
 
 
 def read(run):
-    if run.trace is None:
-        return None
-    calls, secs = run.trace.op_time(
-        lambda name: name.split(".")[0] == KERNEL)
-    per_call = roofline.step_bytes("wsum", run.fold_steps)
-    if not calls or per_call is None:
-        return None
-    return roofline.share_pct(calls * per_call, secs, run.peaks)
+    return roofline.kernel_share(run, "wsum", KERNEL)

@@ -48,3 +48,29 @@ def share_pct(total_bytes: float, seconds: float,
     if not peaks or seconds <= 0 or total_bytes <= 0:
         return None
     return 100.0 * total_bytes / peaks["hbm_bytes_per_s"] / seconds
+
+
+def kernel_share(run, kind: str, kernel: str) -> Optional[float]:
+    """The share of the HBM roofline that ``kernel``'s calls in the
+    traced sub-window reach: fold steps times the bytes of one step of
+    ``kind`` (``step_bytes``), over the kernel's device seconds. None
+    without a trace, a call, or one compiled step of that kind.
+
+    Over a mesh of d chips every fold step calls the kernel once on each
+    chip, on that chip's shard: the trace counts d calls a step, and the
+    compiled step's operand is the global one. So the steps are the
+    calls over the devices in the trace, and the bytes they move are the
+    steps times the global operand's bytes; the seconds are summed over
+    the chips. Each chip moves about 1/d of the bytes in its own time
+    ``t_i``, and ``(bytes / d) / peak / mean(t_i)`` is ``bytes / peak /
+    sum(t_i)``: the mean chip's share of one chip's peak. On one device
+    the steps are the calls."""
+    if run.trace is None:
+        return None
+    calls, secs = run.trace.op_time(
+        lambda name: name.split(".")[0] == kernel)
+    per_step = step_bytes(kind, run.fold_steps)
+    if not calls or per_step is None:
+        return None
+    steps = calls / len(run.trace.per_device)
+    return share_pct(steps * per_step, secs, run.peaks)

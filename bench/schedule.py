@@ -10,7 +10,8 @@ Two shapes of traffic:
     process conditioned on that count. Tenants get equal shares.
   * rounds (``round_uploads``): one tenant's round of
     ``clients_per_round`` uploads, with distinct payload keys in a drawn
-    order.
+    order; from a pool smaller than the round, keys drawn with
+    repetition.
 
 Every upload has its own client id, and its sample count (the FedAvg
 weight) is an integer drawn from the configuration's ``sample_counts``.
@@ -75,12 +76,16 @@ def open_loop_epoch(seed: int, config: dict, traffic: dict,
 
 def round_uploads(seed: int, config: dict, traffic: dict,
                   tenant: int, round_index: int) -> List[Upload]:
-    """One round of tenant ``tenant``: ``clients_per_round`` uploads of
-    distinct payload keys, in the order they are sent."""
+    """One round of tenant ``tenant``: ``clients_per_round`` uploads, in
+    the order they are sent, each with a client id of its own. Their
+    payload keys are distinct where the pool holds a round; a smaller
+    pool is drawn with repetition, so a round of large rows needs few of
+    them on the host."""
     n = config["clients_per_round"]
     pool = traffic.get("payload_pool", n)
     r = rng(seed, _STREAM_ROUND, tenant, round_index)
-    keys = r.permutation(pool)[:n]
+    keys = r.permutation(pool)[:n] if pool >= n else \
+        r.integers(0, pool, size=n)
     weights = _weights(r, config, n)
     name = tenant_names(config)[tenant]
     return [

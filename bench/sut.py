@@ -2,7 +2,10 @@
 ``EdgeAggregatorServer`` (HTTP ingest and the fair round scheduler) over
 one ``AggregationService`` and its ``UpdateStore``, which is the served
 path ``EdgeAggregatorServer -> FairRoundScheduler -> AggregationService
--> LocalEngine -> Pallas fold kernels``.
+-> LocalEngine -> Pallas fold kernels``. A configuration with a
+``"mesh"`` (axis name -> size, the names ``DistributedEngine`` knows:
+``"model"`` shards parameters, ``"data"`` clients) gives the service a
+mesh over the cell's chips, and with it the mesh engines.
 
 The benchmark calls into it only here and in the drivers' uploads, and
 wraps each call in a span of its own (``tracing.span``).
@@ -15,9 +18,11 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
-from bench import harness, schedule, tracing
+from bench import harness, payloads, reference, schedule, tracing
 from bench.harness import RoundRec, UploadRec
+from bench.manifest import mesh_problems
 
 from repro.core import DEFAULT_TENANT, AggregationService, UpdateStore
 from repro.fl import EdgeAggregatorServer
@@ -45,16 +50,33 @@ class RecordingStore(UpdateStore):
             return list(self._consumed.get(tenant, []))
 
 
+def make_mesh(config: dict, chips: int):
+    """The configuration's mesh over the first ``chips`` devices, or
+    None without one. Auto axes: the engines place data with
+    ``NamedSharding`` and ``shard_map``."""
+    axes = config.get("mesh")
+    if axes is None:
+        return None
+    problems = mesh_problems(config, chips)
+    if problems:
+        raise ValueError("; ".join(problems))
+    sizes = tuple(axes.values())
+    return jax.make_mesh(sizes, tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(sizes),
+                         devices=jax.devices()[:chips])
+
+
 class System:
     """The served path for one configuration."""
 
-    def __init__(self, config: dict):
+    def __init__(self, config: dict, chips: int = 1):
         self.config = config
         self.tenants = schedule.tenant_names(config)
         self.tokens = {t: f"tok-{t}" for t in self.tenants}
         self.store = RecordingStore()
         self.service = AggregationService(
-            fusion=config["fusion"], store=self.store, **config["service"])
+            fusion=config["fusion"], store=self.store,
+            mesh=make_mesh(config, chips), **config["service"])
         self.edge = EdgeAggregatorServer(
             self.service, {tok: t for t, tok in self.tokens.items()},
             **config["server"], **config["frontend"])
@@ -94,21 +116,31 @@ class System:
             rec.error = repr(exc)
         return rec
 
+    def engines(self) -> list:
+        """Every engine the service holds: the local one, and the mesh
+        engines where it has a mesh."""
+        svc = self.service
+        return [e for e in (svc.local, svc.distributed, svc.hierarchical)
+                if e is not None]
+
     def counters(self) -> Dict[str, float]:
         q = self.edge.frontend.queue.stats()
         return {
-            "compiles": self.service.local.cache.misses,
+            "compiles": sum(e.cache.misses for e in self.engines()),
             "committed": q["committed"],
             "batches": q["batches"],
         }
 
     def fold_steps(self) -> List[List[Tuple[tuple, str]]]:
         """The operands, ``(shape, dtype)``, of each fold step the
-        engine compiled: the shapes its kernels run at."""
+        engines compiled: the shapes their kernels run at, whole over a
+        mesh."""
         out = []
-        for fn in self.service.local.cache.executables().values():
-            args = jax.tree_util.tree_leaves(getattr(fn, "args_info", ()))
-            out.append([(tuple(a.shape), str(a.dtype)) for a in args])
+        for engine in self.engines():
+            for fn in engine.cache.executables().values():
+                args = jax.tree_util.tree_leaves(
+                    getattr(fn, "args_info", ()))
+                out.append([(tuple(a.shape), str(a.dtype)) for a in args])
         return out
 
     def leftover(self) -> Dict[str, List[str]]:
@@ -121,25 +153,42 @@ class System:
 class Session:
     """What every driver shares: the system under test, the rounds and
     uploads recorded, the traced sub-window, the measured window, and
-    the ``Run`` built from them. A driver brings only its traffic."""
+    the ``Run`` built from them. A driver brings only its traffic.
+
+    A window round keeps its fused vector until the run ends, and is
+    checked then; but a ranged configuration's (``payloads.ranged``:
+    rows too large to keep a fused vector a round) is checked in
+    ``record``, as the driver hands it over with the uploads it folded
+    and before that tenant's next round is submitted, and keeps only its
+    ``(gap, scale)``. Only drivers that record a round's uploads with it
+    can do that (``manifest.check_problems``). The time those checks
+    take (``paused``) is left out of the readings over the window's
+    time: ``Run.measured`` and the traced window's busy and length."""
 
     def __init__(self, ctx):
         self.ctx = ctx
-        self.system = System(ctx.cell.config)
+        self.each_round = payloads.ranged(ctx.cell.config)
+        self.system = System(ctx.cell.config, ctx.cell.chips)
         self.traced = TracedWindow(ctx, ctx.cell.traffic)
         self.rounds: List[RoundRec] = []
         self.uploads: List[UploadRec] = []
+        self._by_cid: Dict[str, UploadRec] = {}
         self.stop = threading.Event()   # set at the window's end
         self.window: Optional[Tuple[float, float]] = None
         self._lock = threading.Lock()
         self._done = {t: 0 for t in self.system.tenants}
         self._state: dict = {}
+        self.paused: List[Tuple[float, float]] = []
 
     def record(self, rounds: Sequence[RoundRec],
                uploads: Sequence[UploadRec] = ()) -> None:
         """Keep finished rounds and their uploads. Only rounds started
         in the window are judged, so the others' fused vectors go at
-        once."""
+        once; a ranged configuration's window rounds are checked
+        here."""
+        if self.each_round:
+            for rec in rounds:
+                self.check_round(rec, uploads)
         with self._lock:
             for rec in rounds:
                 if self.window is None or not (
@@ -148,8 +197,34 @@ class Session:
                 self.rounds.append(rec)
                 self._done[rec.tenant] += 1
             self.uploads.extend(uploads)
+            self._by_cid.update((u.cid, u) for u in uploads)
         for _ in rounds:
             self.traced.round_done()
+
+    def check_round(self, rec: RoundRec,
+                    uploads: Sequence[UploadRec]) -> None:
+        """Compare a window round with the reference now, keep its
+        ``(gap, scale)`` and drop its fused vector. A round whose
+        uploads do not match what it folded is not compared: the check
+        after the window counts it failed."""
+        window = self.window
+        if rec.error is not None or rec.fused is None or window is None \
+                or not window[0] <= rec.started < window[1]:
+            return
+        by_cid = {u.cid: u for u in uploads}
+        with self._lock:
+            by_cid.update((c, self._by_cid[c]) for c in rec.included
+                          if c in self._by_cid and c not in by_cid)
+        included = harness.included_pairs(rec, by_cid)
+        t0 = time.monotonic()
+        if included is not None:
+            with tracing.span("check_round"):
+                rec.checked = reference.check_round(
+                    self.ctx.seed, self.ctx.cell.config, included,
+                    rec.fused)
+        rec.fused = None
+        with self._lock:
+            self.paused.append((t0, time.monotonic()))
 
     def tenant_rounds(self, tenant: str,
                       land: Callable[[int], Sequence[UploadRec]] = None
@@ -215,7 +290,8 @@ class Session:
         return harness.Run(
             ctx=self.ctx, setup_s=self.window[0] - self.ctx.started,
             window=self.window, uploads=self.uploads, rounds=self.rounds,
-            trace=self.traced.reduced(), **self._state)
+            trace=self.traced.reduced(self.paused), paused=self.paused,
+            **self._state)
 
 
 class TracedWindow:
@@ -258,15 +334,23 @@ class TracedWindow:
         if self.due():
             self.stop()
 
-    def reduced(self):
+    def reduced(self, paused: Sequence[Tuple[float, float]] = ()):
+        """The traced window, with the checks in it (``paused``) left
+        out of its time."""
         if self.profile is None or tracing.STOP not in self.profile.marks:
             return None
-        return tracing.reduce(self.profile.path(), self.profile.marks)
+        out = tracing.reduce(self.profile.path(), self.profile.marks)
+        out.paused = list(paused)
+        return out
 
 
 def update_of(config: dict, payload):
     """A payload (``bench.payloads.make``) as the program's upload
-    type: an fp32 vector, or a ``CompressedUpdate``."""
+    type: a dense vector, or a ``CompressedUpdate``. Its arrays reach
+    the program read-only, so the program cannot alter a row that
+    later uploads carry."""
+    for a in payload if isinstance(payload, tuple) else (payload,):
+        a.flags.writeable = False
     if config["payload"]["kind"] != "int8":
         return payload
     from repro.core.compress import CompressedUpdate

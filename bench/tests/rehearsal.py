@@ -23,11 +23,15 @@ TINY = {
 TINY_TRAFFIC = {"payload_pool": 16, "rate_per_s": 40, "senders": 4}
 
 
-def tiny_cell(name: str) -> harness.Cell:
+def tiny_cell(name: str, payload: dict = None) -> harness.Cell:
+    """The cell at a tiny size; with ``payload``, its configuration's
+    payload replaced (a bf16 one: rounds checked as each ends)."""
     with open(harness.ROOT / "BENCHMARK.json") as f:
         cell = harness.resolve(json.load(f), name)
     kind = cell.config["payload"]["kind"]
     cell.config = dict(cell.config, **TINY[kind])
+    if payload is not None:
+        cell.config["payload"] = payload
     # three int8 rows per streamed block, so rounds fold several blocks
     cell.config["service"] = dict(cell.config["service"],
                                   stream_chunk_bytes=3 * 4200)
@@ -37,13 +41,34 @@ def tiny_cell(name: str) -> harness.Cell:
 
 
 def rehearse(name: str, tmp_path, seconds: float = 1.0,
-             trace: bool = False) -> dict:
+             trace: bool = False, payload: dict = None) -> dict:
     started = time.monotonic()
     device, peaks = harness.device_info(1, require_tpu=False)
-    ctx = harness.Context(cell=tiny_cell(name), seed=2**31 + 77,
+    ctx = harness.Context(cell=tiny_cell(name, payload), seed=2**31 + 77,
                           seconds=seconds, trace=trace, started=started,
                           trace_dir=str(tmp_path))
     return harness.run_cell(ctx, device, peaks)
+
+
+# a payload whose rounds are checked as each ends
+BF16 = {"kind": "bfloat16"}
+
+
+def rehearse_keeping_run(name: str, tmp_path, monkeypatch, **kw):
+    """``rehearse``, and the ``Run`` its result line was read from."""
+    runs = []
+    result = harness.result
+    monkeypatch.setattr(harness, "result", lambda run, device: (
+        runs.append(run) or result(run, device)))
+    return rehearse(name, tmp_path, **kw), runs[0]
+
+
+def checked_as_each_ended(run) -> bool:
+    """Every window round was checked as it ended and kept no fused
+    vector, and each check's time is left out of the window."""
+    window = run.window_rounds()
+    return bool(window) and len(run.paused) == len(window) and all(
+        r.checked is not None and r.fused is None for r in window)
 
 
 def _state_unchanged(orig):
@@ -67,25 +92,47 @@ def _half_batch(orig):
 
 def _answer_altered(orig):
     def finalize(self, state):
-        out = orig(self, state)
+        out = jnp.asarray(orig(self, state))   # numpy on the mesh engine
         return out.at[0].add(1e-4 * jnp.max(jnp.abs(out)))
     return finalize
 
 
-# name -> (class, method, wrapper): a fold step that returns its state
+# name -> [(class, method, wrapper)]: a fold step that returns its state
 # unchanged; every other row of a round left out, the mean taken over
-# the rest; one value of the answer altered where it is produced
+# the rest, on either engine; one value of the answer altered where it
+# is produced
 FAULTS = {
-    "state_unchanged": ("FedAvg", "fold_block", _state_unchanged),
-    "half_batch": ("LocalEngine", "fuse_stream", _half_batch),
-    "answer_altered": ("FedAvg", "finalize", _answer_altered),
+    "state_unchanged": [("FedAvg", "fold_block", _state_unchanged)],
+    "half_batch": [("LocalEngine", "fuse_stream", _half_batch),
+                   ("DistributedEngine", "fuse_stream", _half_batch)],
+    "answer_altered": [("FedAvg", "finalize", _answer_altered)],
 }
 
 
 def plant(monkeypatch, fault: str) -> None:
+    from repro.core.distributed import DistributedEngine
     from repro.core.fusion.averaging import FedAvg
     from repro.core.local import LocalEngine
 
-    owner, method, wrap = FAULTS[fault]
-    cls = {"FedAvg": FedAvg, "LocalEngine": LocalEngine}[owner]
-    monkeypatch.setattr(cls, method, wrap(getattr(cls, method)))
+    classes = {"FedAvg": FedAvg, "LocalEngine": LocalEngine,
+               "DistributedEngine": DistributedEngine}
+    for owner, method, wrap in FAULTS[fault]:
+        cls = classes[owner]
+        monkeypatch.setattr(cls, method, wrap(getattr(cls, method)))
+
+
+def force_mesh_plan(monkeypatch) -> None:
+    """Make the planner pick the mesh engine wherever the service has a
+    mesh: at a rehearsal's size it would fold on one chip."""
+    from repro.core.planner import Planner
+
+    plan = Planner.plan
+
+    def on_the_mesh(self, load, fusion, warm_engines=()):
+        plans = [p for p in self.candidate_plans(load, fusion, warm_engines)
+                 if p.feasible and p.engine != "local"]
+        if self.n_devices > 1 and plans:
+            return min(plans, key=lambda p: p.est_seconds)
+        return plan(self, load, fusion, warm_engines)
+
+    monkeypatch.setattr(Planner, "plan", on_the_mesh)

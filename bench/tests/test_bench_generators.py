@@ -1,4 +1,8 @@
 """The payload generators and the schedules depend on the seed alone."""
+import hashlib
+import tracemalloc
+
+import ml_dtypes
 import numpy as np
 import pytest
 
@@ -70,3 +74,81 @@ def test_round_uploads_are_seeded(config, traffic):
     assert all(lo <= u.weight <= hi for u in a)
     later = schedule.round_uploads(SEED, config, traffic, 0, 4)
     assert not {u.cid for u in a} & {u.cid for u in later}
+
+
+BF16 = {"params": 3 * payloads.TILE + 123, "payload": {"kind": "bfloat16"},
+        "tenants": 1, "clients_per_round": 3, "sample_counts": [1000, 100000]}
+T = payloads.TILE
+
+
+def _sha(*arrays) -> str:
+    return hashlib.sha256(b"".join(np.ascontiguousarray(a).tobytes()
+                                   for a in arrays)).hexdigest()
+
+
+@pytest.mark.parametrize("config,key,digest", [
+    (DENSE, 3,
+     "2fe5cb8a21750d66f8697af050eee66f6b70da776d554af6d435a4b2bf5f4f83"),
+    (INT8, 7,
+     "5aed2f08fcc942ea929ebddd962c7a39b2b7ad2d172e15c0afd446f1a29d0290"),
+])
+def test_whole_payloads_keep_their_bytes(config, key, digest):
+    # the bytes these seeds gave before bf16 payloads were added
+    made = payloads.make(SEED, config, key)
+    assert _sha(*(made if isinstance(made, tuple) else (made,))) == digest
+
+
+def test_bf16_payload_is_seeded():
+    a = payloads.make(SEED, BF16, 1)
+    assert a.dtype == ml_dtypes.bfloat16 and a.shape == (BF16["params"],)
+    np.testing.assert_array_equal(a, payloads.make(SEED, BF16, 1))
+    assert not np.array_equal(a, payloads.make(SEED, BF16, 0))
+    assert not np.array_equal(a, payloads.make(SEED + 1, BF16, 1))
+    assert np.isfinite(a.astype(np.float32)).all()
+    assert 0.9 < a.astype(np.float64).std() < 1.1
+
+
+@pytest.mark.parametrize("lo,hi", [
+    (0, 1), (5, 5), (T - 5, T + 7), (T, 2 * T), (T + 1, 3 * T - 1),
+    (0, 3 * T + 123), (3 * T, 3 * T + 123), (3 * T + 122, 3 * T + 123)])
+def test_make_range_is_the_slice_of_make(lo, hi):
+    part = payloads.make_range(SEED, BF16, 2, lo, hi)
+    assert part.dtype == ml_dtypes.bfloat16 and part.shape == (hi - lo,)
+    np.testing.assert_array_equal(part, payloads.make(SEED, BF16, 2)[lo:hi])
+
+
+def test_make_range_refuses_what_it_cannot_make():
+    with pytest.raises(ValueError):
+        payloads.make_range(SEED, BF16, 0, 0, BF16["params"] + 1)
+    with pytest.raises(ValueError):
+        payloads.make_range(SEED, DENSE, 0, 0, 10)
+
+
+def test_bf16_row_has_no_whole_fp32_copy():
+    config = dict(BF16, params=64 * T)
+    tracemalloc.start()
+    try:
+        row = payloads.make(SEED, config, 0)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    # the bf16 row and one fp32 tile per thread; a whole fp32 row
+    # alone would be twice the bf16 row
+    assert row.nbytes <= peak < row.nbytes + payloads.workers() * 8 * T
+    assert peak < 4 * config["params"]
+
+
+def test_round_keys_from_the_pool_are_unchanged():
+    # the draw these seeds gave before small pools were allowed
+    ups = schedule.round_uploads(SEED, INT8, {"payload_pool": 16}, 0, 1)
+    assert [u.key for u in ups] == [11, 0, 5, 6, 10, 1, 12, 4]
+
+
+def test_pool_smaller_than_a_round_reuses_keys():
+    config = dict(DENSE, clients_per_round=8)
+    a = schedule.round_uploads(SEED, config, {"payload_pool": 2}, 0, 3)
+    assert a == schedule.round_uploads(SEED, config, {"payload_pool": 2},
+                                       0, 3)
+    keys = [u.key for u in a]
+    assert len(a) == 8 and set(keys) == {0, 1}
+    assert len({u.cid for u in a}) == 8

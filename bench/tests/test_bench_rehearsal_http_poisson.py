@@ -31,3 +31,10 @@ def test_traced_rehearsal_reads_per_layer_metrics(tmp_path):
         set(line["metrics"])
     assert line["metrics"]["compiles_in_window.poisson"]["value"] == 0
     assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
+
+
+def test_bf16_rounds_are_refused(tmp_path):
+    # its uploads are recorded only at the run's end, so it cannot check
+    # a round as it ends: refused before anything starts
+    with pytest.raises(ValueError, match="checked as each ends"):
+        rehearsal.rehearse(CELL, tmp_path, payload=rehearsal.BF16)

@@ -30,3 +30,20 @@ def test_traced_rehearsal_reads_per_layer_metrics(tmp_path):
         set(line["metrics"])
     assert line["metrics"]["compiles_in_window.silo"]["value"] == 0
     assert 0 < line["device"]["busy_s"] <= line["device"]["window_s"]
+
+
+def test_bf16_rounds_are_checked_as_each_ends(tmp_path, monkeypatch):
+    # each compared with the reference before the next is
+    # submitted, keeping only its (gap, scale)
+    line, run = rehearsal.rehearse_keeping_run(
+        CELL, tmp_path, monkeypatch, payload=rehearsal.BF16)
+    assert line["correct"], line["checks"]
+    assert rehearsal.checked_as_each_ended(run)
+
+
+def test_bf16_fault_is_caught_as_each_round_ends(tmp_path, monkeypatch):
+    rehearsal.plant(monkeypatch, "answer_altered")
+    line, run = rehearsal.rehearse_keeping_run(
+        CELL, tmp_path, monkeypatch, payload=rehearsal.BF16)
+    assert not line["correct"], line["checks"]
+    assert rehearsal.checked_as_each_ended(run)

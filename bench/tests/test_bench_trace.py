@@ -68,3 +68,46 @@ def test_reduce_a_recorded_cpu_profile(tmp_path):
     br = red.breakdown()
     assert 0 < len(br["device_ops"]) <= 10
     assert all(secs > 0 for _, secs in br["device_ops"])
+
+
+def test_busy_within_averages_over_devices():
+    one = tracing.Reduced(
+        window=(0.0, 10.0), per_device=[[(0.0, 4.0), (5.0, 6.0)]],
+        ops={}, spans=[], drift_s=0.0)
+    # one device: the union, as before
+    assert one.busy_within([(1.0, 5.5)]) == 3.5
+    two = tracing.Reduced(
+        window=(0.0, 10.0), per_device=[[(0.0, 4.0)], [(2.0, 6.0)]],
+        ops={}, spans=[], drift_s=0.0)
+    # the union would read 6.0: each chip was busy 4 of the 6 seconds
+    assert two.busy_within([(0.0, 6.0)]) == 4.0
+    assert two.busy_within([(0.0, 6.0)]) == two.busy_s
+
+
+def test_checks_are_no_part_of_the_traced_window():
+    red = tracing.Reduced(
+        window=(0.0, 10.0), per_device=[[(0.0, 2.0), (3.0, 5.0)],
+                                        [(1.0, 2.0)]],
+        ops={}, spans=[], drift_s=0.0, paused=[(4.0, 6.0), (9.0, 12.0)])
+    assert red.window_s == 7.0
+    # device 0: 4 s busy, 1 of them inside a check; device 1: 1 s
+    assert red.busy_s == 2.0
+    assert red.idle_gaps() == [("outside benchmark spans", 3.0),
+                               ("outside benchmark spans", 1.0)]
+    red.paused = []
+    assert (red.window_s, red.busy_s) == (10.0, 2.5)
+
+
+def test_round_s_leaves_the_checks_out():
+    from bench import harness
+
+    rounds = [harness.RoundRec(tenant="t0", started=s, on_host=e)
+              for s, e in [(0.0, 3.0), (4.0, 7.0), (8.0, 11.0)]]
+    run = harness.Run(ctx=None, setup_s=0.0, window=(0.0, 12.0),
+                      uploads=[], rounds=rounds, counters={}, leftover={},
+                      paused=[(3.0, 4.0), (7.0, 8.0), (11.0, 12.0)])
+    assert run.measured(0.0, 12.0) == 9.0
+    round_s = harness.load_module(harness.BENCH / "metrics" / "round_s.py")
+    assert round_s.read(run) == 3.0
+    run.paused = []
+    assert round_s.read(run) == 11.0 / 3

@@ -99,25 +99,42 @@ class Reduced:
     ops: Dict[str, Tuple[int, float]]    # op name -> (count, seconds)
     spans: List[Tuple[str, float, float]]  # the benchmark's host spans
     drift_s: float                       # clock offset change, stop - start
+    # the benchmark's own checks of rounds as they ended: no part of the
+    # window (``harness.Run.paused``)
+    paused: List[Interval] = dataclasses.field(default_factory=list)
 
     @property
     def busy(self) -> List[Interval]:
         """Intervals in which some device ran an operation."""
         return merge([iv for dev in self.per_device for iv in dev])
 
+    def _paused_in_window(self) -> List[Interval]:
+        lo, hi = self.window
+        return [(max(a, lo), min(b, hi)) for a, b in merge(self.paused)
+                if b > lo and a < hi]
+
     @property
     def window_s(self) -> float:
-        return self.window[1] - self.window[0]
+        """The traced window's length, less the checks in it."""
+        return self.window[1] - self.window[0] - sum(
+            b - a for a, b in self._paused_in_window())
 
     @property
     def busy_s(self) -> float:
-        """Device-busy seconds in the window, averaged over devices."""
+        """Device-busy seconds in the window, outside the checks,
+        averaged over devices."""
+        paused = self._paused_in_window()
         return sum(covered(dev, *self.window)
+                   - sum(covered(dev, a, b) for a, b in paused)
                    for dev in self.per_device) / len(self.per_device)
 
     def busy_within(self, intervals: Sequence[Interval]) -> float:
-        busy = self.busy
-        return sum(covered(busy, a, b) for a, b in intervals)
+        """Device-busy seconds within ``intervals``, averaged over
+        devices as ``busy_s`` is: the union over several chips would
+        count a chip busy while another works."""
+        devices = [merge(dev) for dev in self.per_device]
+        return sum(sum(covered(dev, a, b) for a, b in intervals)
+                   for dev in devices) / len(devices)
 
     def op_time(self, match) -> Tuple[int, float]:
         """(calls, seconds) over the ops whose name ``match`` accepts."""
@@ -130,9 +147,11 @@ class Reduced:
 
     def idle_gaps(self) -> List[Tuple[str, float]]:
         """The device's idle gaps in the window, longest first, each
-        labelled with the innermost benchmark span around its middle."""
+        labelled with the innermost benchmark span around its middle.
+        The checks are no gaps: they are no part of the window."""
         lo, hi = self.window
-        busy = [iv for iv in self.busy if iv[1] > lo and iv[0] < hi]
+        busy = [iv for iv in merge(self.busy + self.paused)
+                if iv[1] > lo and iv[0] < hi]
         edges = [lo] + [x for a, b in busy for x in (a, b)] + [hi]
         gaps = []
         for a, b in zip(edges[0::2], edges[1::2]):
